@@ -14,6 +14,8 @@ import sys
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("section,name,us_per_call,derived")
 
     # --- Table 1 / Figure 9: the 27-benchmark Amdahl suite ------------------

@@ -322,7 +322,8 @@ class OpticalFourierAcceleratorSpec:
                 "resident_frames + len(delta_fractions) exceeds batch")
         if tile_k is None and mem_budget is not None:
             tile_k = mem_budget.tile_for_group(
-                n_in, n_out, batch, pipeline_depth=pipeline_depth)
+                n_in, n_out, batch, pipeline_depth=pipeline_depth,
+                n_devices=n_devices)
         if tile_k is not None and tile_k < 1:
             raise ValueError("tile_k must be >= 1")
         sizes = tile_sizes(batch, batch if tile_k is None else tile_k)
@@ -478,7 +479,8 @@ class OpticalFourierAcceleratorSpec:
         with the faster side charged only its exposed prologue share.
         ``tile_k >= batch`` is exactly the monolithic price; ``tile_k=1``
         prices the looped regime.  Alternatively pass ``mem_budget`` (any
-        object with a ``tile_for_group(n_in, n_out, k, pipeline_depth=...)``
+        object with a ``tile_for_group(n_in, n_out, k, pipeline_depth=...,
+        n_devices=...)``
         method, e.g. ``repro.runtime.tiling.MemoryBudget``) and the tile
         depth is derived from the byte budget exactly as the executor
         derives it — same frame cap, same even-split divisor refinement.
@@ -619,7 +621,8 @@ class OpticalMVMAcceleratorSpec:
                 "resident_frames + len(delta_fractions) exceeds batch")
         if tile_k is None and mem_budget is not None:
             tile_k = mem_budget.tile_for_group(
-                n_in, n_out, batch, pipeline_depth=pipeline_depth)
+                n_in, n_out, batch, pipeline_depth=pipeline_depth,
+                n_devices=n_devices)
         if tile_k is not None and tile_k < 1:
             raise ValueError("tile_k must be >= 1")
         sizes = tile_sizes(batch, batch if tile_k is None else tile_k)
@@ -724,7 +727,7 @@ class OpticalMVMAcceleratorSpec:
         (``interface_latency_s``) and — under sharding — its own per-device
         sync, with consecutive tiles overlapped two-deep when
         ``pipeline_depth >= 2``.  ``mem_budget`` duck-types
-        ``tile_for_group(n_in, n_out, k, pipeline_depth=...)``
+        ``tile_for_group(n_in, n_out, k, pipeline_depth=..., n_devices=...)``
         (``repro.runtime.tiling.MemoryBudget``) — the executor's exact
         resolution, divisor refinement included.
 

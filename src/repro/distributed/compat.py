@@ -1,11 +1,9 @@
-"""jax version compatibility for mesh construction and activation.
+"""Mesh construction and activation on the installed jax mesh API.
 
-The repo targets the current jax mesh API (``jax.make_mesh(...,
-axis_types=...)`` + ``jax.set_mesh`` + ``jax.sharding.get_abstract_mesh``)
-but must also run on jax 0.4.x, where axis types don't exist, the context
-mesh is the ``with mesh:`` resource env, and the abstract mesh is not
-threaded through tracing.  All mesh touch-points go through this module so
-the rest of the codebase is version-agnostic.
+``jax.make_mesh(..., axis_types=...)`` builds meshes, ``jax.set_mesh``
+activates them, ``jax.sharding.get_abstract_mesh`` reports the context mesh
+while tracing and ``jax.sharding.get_mesh`` the concrete one with devices
+attached.  All mesh touch-points go through this module.
 """
 
 from __future__ import annotations
@@ -15,75 +13,32 @@ import jax
 __all__ = ["make_auto_mesh", "enter_mesh", "current_mesh_axis_names",
            "current_mesh"]
 
-# Last mesh activated through enter_mesh — the version-agnostic fallback for
-# current_mesh() when neither the new concrete-mesh API nor the 0.4.x
-# resource env can report one.
-_LAST_ENTERED: jax.sharding.Mesh | None = None
-
 
 def make_auto_mesh(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    try:
-        auto = jax.sharding.AxisType.Auto
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(auto,) * len(axes))
-    except (AttributeError, TypeError):  # jax 0.4.x: no axis_types
-        return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with Auto axis types on every axis."""
+    auto = jax.sharding.AxisType.Auto
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(auto,) * len(axes))
 
 
 def enter_mesh(mesh: jax.sharding.Mesh) -> None:
-    """Make ``mesh`` the context mesh for the rest of the process.
-
-    New jax: ``jax.set_mesh``.  jax 0.4.x: enter the ``with mesh:`` resource
-    env and deliberately never exit (callers are process-scoped scripts —
-    dry-run cells and subprocess lowering tests)."""
-    global _LAST_ENTERED
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        set_mesh(mesh)
-    else:
-        mesh.__enter__()
-    _LAST_ENTERED = mesh
+    """Make ``mesh`` the context mesh for the rest of the process
+    (callers are process-scoped scripts: dry-run cells and subprocess
+    lowering tests)."""
+    jax.set_mesh(mesh)
 
 
 def current_mesh_axis_names() -> tuple[str, ...]:
-    """Axis names of the active (abstract or resource-env) context mesh."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is None:
-        from jax._src import mesh as _mesh_lib
-        get_abstract = getattr(_mesh_lib, "get_abstract_mesh", lambda: None)
-    mesh = get_abstract()
-    if mesh is not None and not getattr(mesh, "empty", True):
-        return tuple(mesh.axis_names)
-    try:  # jax 0.4.x ``with mesh:`` resource env
-        from jax._src import mesh as _mesh_lib
-        env_mesh = _mesh_lib.thread_resources.env.physical_mesh
-        if not env_mesh.empty:
-            return tuple(env_mesh.axis_names)
-    except (ImportError, AttributeError):
-        pass
-    return ()
+    """Axis names of the active context mesh (empty off-mesh)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return () if mesh.empty else tuple(mesh.axis_names)
 
 
 def current_mesh() -> jax.sharding.Mesh | None:
     """The active *concrete* context mesh, or None off-mesh.
 
-    Unlike :func:`current_mesh_axis_names` this must return a mesh with
-    real devices attached (the sharded offload backend scatters work onto
-    them), so the abstract-mesh path is skipped: new jax goes through
-    ``get_concrete_mesh``, 0.4.x through the resource env, and the
-    :func:`enter_mesh` bookkeeping covers whichever API recorded neither.
+    Unlike :func:`current_mesh_axis_names` this returns a mesh with real
+    devices attached: the sharded offload backend scatters work onto them.
     """
-    get_concrete = getattr(jax.sharding, "get_concrete_mesh", None)
-    if get_concrete is not None:
-        mesh = get_concrete()
-        if mesh is not None and not getattr(mesh, "empty", True):
-            return mesh
-    try:  # jax 0.4.x ``with mesh:`` resource env
-        from jax._src import mesh as _mesh_lib
-        env_mesh = _mesh_lib.thread_resources.env.physical_mesh
-        if not env_mesh.empty:
-            return env_mesh
-    except (ImportError, AttributeError):
-        pass
-    return _LAST_ENTERED
+    mesh = jax.sharding.get_mesh()
+    return None if mesh.empty else mesh

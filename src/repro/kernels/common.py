@@ -24,15 +24,17 @@ def round_up(x: int, m: int) -> int:
 
 
 def pick_block(dim: int, preferred: int, align: int) -> int:
-    """Largest block <= preferred that divides ``dim``; falls back to dim.
+    """Largest multiple of ``align`` <= max(preferred, align) that divides
+    ``dim``; falls back to ``dim`` itself.
 
-    Keeps MXU alignment when the dimension allows it — callers pad inputs to
-    ``align`` multiples before invoking kernels, so the fallback only fires
-    for deliberately tiny test shapes.
+    A TPU block's last two dimensions must be multiples of (8, 128) or span
+    the whole array, so the block never drops below ``align``: a preferred
+    size under it rounds up to ``align``, and a dimension no aligned block
+    divides is taken whole (deliberately tiny test shapes).
     """
-    if dim >= preferred and dim % preferred == 0:
-        return preferred
-    b = min(dim, preferred)
-    while b > align and dim % b != 0:
+    b = min(max(preferred, align) // align * align, dim // align * align)
+    while b >= align:
+        if dim % b == 0:
+            return b
         b -= align
-    return b if dim % b == 0 else dim
+    return dim

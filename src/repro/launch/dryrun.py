@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=512").strip()
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this script builds the real step function (train / prefill /
@@ -25,6 +21,7 @@ Usage:
 import argparse
 import dataclasses
 import json
+import os
 import re
 import time
 import traceback
@@ -323,6 +320,11 @@ def all_cells() -> list[tuple[str, str]]:
 
 
 def main() -> None:
+    # 512 placeholder CPU devices for the production meshes; read by the
+    # backend at its first use, which no import above triggers
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=512").strip()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

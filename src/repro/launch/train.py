@@ -21,6 +21,7 @@ from repro import configs as cfgs
 from repro.checkpoint import CheckpointManager
 from repro.data import MarkovTask
 from repro.distributed.fault import FaultTolerantRunner
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import LM, init_params
 from repro.optim import adamw, warmup_cosine
 from repro.train import make_train_step
@@ -90,6 +91,7 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=3e-3)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     t0 = time.time()
     _, losses, task = train_loop(args.arch, smoke=args.smoke, steps=args.steps,
                                  batch=args.batch, seq=args.seq,

@@ -443,7 +443,8 @@ class OffloadExecutor:
         return dict(self._category_tile_k)
 
     def resolve_tile_k(self, category: str, x: jax.Array, depth: int, *,
-                       weights: jax.Array | None = None) -> int:
+                       weights: jax.Array | None = None,
+                       backend: str | None = None) -> int:
         """Frames per sub-invocation for a ``depth``-deep released run of
         ``x``-shaped calls: the per-category pin, the global ``tile_k``
         override, or — when neither is set — :func:`choose_tile` against
@@ -453,18 +454,31 @@ class OffloadExecutor:
         are exactly the stack shapes flushed and the planned tile is the
         dispatched tile.  The per-call output size enters the working-set
         model too — a matmul's result footprint is set by the weights'
-        trailing dim, not the operand's."""
+        trailing dim, not the operand's.  A ``backend`` that shards
+        (:meth:`shards`) spreads each tile over the category's device
+        fan-out, each device staging its share under the per-device
+        budget."""
         t = self._category_tile_k.get(category, self.tile_k)
         if t is None:
             n_out = (int(x.shape[0]) * int(weights.shape[-1])
                      if category == "matmul" and weights is not None
                      else int(x.size))
+            fan_out = self.n_devices_for(category) \
+                if self.shards(backend) else 1
             t = choose_tile(int(x.size), depth, self.effective_mem_budget(),
                             n_out=n_out,
                             dtype_bytes=max(1, x.dtype.itemsize),
                             pipeline_depth=self.pipeline_window_for(
-                                category)).tile_k
+                                category),
+                            n_devices=fan_out).tile_k
         return max(1, min(int(t), depth))
+
+    def shards(self, backend: str | None = None) -> bool:
+        """Whether ``backend`` (the default backend when None) splits each
+        invocation across the device fan-out: the sharded backends commit
+        device placements, nothing else does."""
+        be = self._backend(backend or self.default_backend)
+        return getattr(be, "commit_placement", None) is not None
 
     def effective_mem_budget(self) -> MemoryBudget:
         """The staging budget tiles are chosen against *right now*: the
@@ -628,7 +642,8 @@ class OffloadExecutor:
             self.ctx.n_devices, self.n_devices_for(category)
         saved_pd, self.ctx.pipeline_depth = \
             self.ctx.pipeline_depth, self.pipeline_window_for(category)
-        tile = self.resolve_tile_k(category, x, batch, weights=weights)
+        tile = self.resolve_tile_k(category, x, batch, weights=weights,
+                                   backend=name)
         # warm-up runs are not workload: suppress backend-side tracing so
         # priming does not litter the trace with orphan device spans, the
         # straggler watchdog so first-call compile time can never strike
@@ -816,7 +831,8 @@ class OffloadExecutor:
         """
         head = chunk[0]
         tile = self.resolve_tile_k(head.category, head.x, len(chunk),
-                                   weights=head.weights)
+                                   weights=head.weights,
+                                   backend=head.backend)
         start = 0
         sizes = tile_sizes(len(chunk), tile)
         # Device-resident sharded dispatch: commit ONE sharded placement
@@ -1084,13 +1100,19 @@ class OffloadExecutor:
             # caller did unrelated host work between flush_async and
             # wait()/drain().  Wall-clock would bill that idle time to the
             # invocation (and poison the profiles replan derives); charge
-            # only the host-side staging+dispatch window we observed.
-            wall = f.dispatch_s
+            # only the part of the host-side staging+dispatch window we
+            # observed that no earlier retirement billed.  The next
+            # retirement charges from the end of that window, not from
+            # now: invocations dispatched after it (staged while this one
+            # computed) still owe their own staging.
+            end = f.t0 + f.dispatch_s
+            wall = max(0.0, end - max(f.t0, self._last_retire_end))
+            self._last_retire_end = max(self._last_retire_end, end)
         else:
             # overlapped invocations must not double-count shared wall
             # time: charge only from where the previous retirement ended
             wall = now - max(f.t0, self._last_retire_end)
-        self._last_retire_end = now
+            self._last_retire_end = now
         batch = len(f.chunk)
         samples_in = sum(int(p.x.size) for p in f.chunk)
         samples_out = sum(int(o.size) for o in f.outs)

@@ -128,7 +128,8 @@ class PlanRouter:
         are monotone non-increasing as the deadline tightens (the halving
         sequence is fixed, so a smaller deadline only ever stops it
         later); ``tile_k`` never exceeds the chosen batch or the budget's
-        frame cap, but its even-split refinement may legitimately pick a
+        per-device frame cap times the devices a sharded tile spreads
+        over, but its even-split refinement may legitimately pick a
         *larger* divisor at a smaller batch (a 6-deep group tiles 3+3
         where a 16-deep one tiles 2x8 under the same cap).
 
@@ -155,6 +156,7 @@ class PlanRouter:
                 avail = ex.n_devices - q.active_device_count(ex.now())
                 n_cap = max(1, min(n_cap, avail))
             tile_cap = self._operator_tile_bound(cat)
+            shards = ex.shards(self.offload_backend)
             n_in, n_out = telemetry.samples_per_call(cat)
 
             def tile_for(depth: int) -> int:
@@ -165,7 +167,9 @@ class PlanRouter:
                 # the dispatcher will actually have left
                 t = choose_tile(n_in, depth, ex.effective_mem_budget(),
                                 n_out=n_out or None,
-                                pipeline_depth=ex.pipeline_depth).tile_k
+                                pipeline_depth=ex.pipeline_depth,
+                                n_devices=(max(1, min(n_cap, depth))
+                                           if shards else 1)).tile_k
                 if tile_cap is not None:
                     t = min(t, tile_cap)
                 return max(1, min(t, depth))

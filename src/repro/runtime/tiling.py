@@ -234,7 +234,8 @@ class MemoryBudget:
 
     def tile_for_group(self, n_in: int, n_out: int | None, k: int, *,
                        pipeline_depth: int = 2,
-                       dtype_bytes: int = BYTES_F32) -> int:
+                       dtype_bytes: int = BYTES_F32,
+                       n_devices: int = 1) -> int:
         """The tile depth a ``k``-deep group actually dispatches at: the
         budget frame cap refined by :func:`choose_tile`'s even-split
         divisor preference.  This is the resolution the executor, the
@@ -245,7 +246,8 @@ class MemoryBudget:
         refinement included."""
         return choose_tile(n_in, k, self, n_out=n_out,
                            dtype_bytes=dtype_bytes,
-                           pipeline_depth=pipeline_depth).tile_k
+                           pipeline_depth=pipeline_depth,
+                           n_devices=n_devices).tile_k
 
 
 
@@ -280,7 +282,7 @@ class TilePlan:
 
 def choose_tile(n_in: int, k: int, budget: MemoryBudget, *,
                 n_out: int | None = None, dtype_bytes: int = BYTES_F32,
-                pipeline_depth: int = 2) -> TilePlan:
+                pipeline_depth: int = 2, n_devices: int = 1) -> TilePlan:
     """Pick ``tile_k`` for a K-deep group of ``n_in``-sample frames.
 
     The deepest tile whose working set (times ``pipeline_depth`` — two
@@ -290,12 +292,18 @@ def choose_tile(n_in: int, k: int, budget: MemoryBudget, *,
     tile, which is one fewer compiled stack shape and one fewer
     under-filled boundary crossing, at the cost of at most half the
     budgeted amortization depth.
+
+    ``n_devices`` is how many devices one tile spreads over: group-sharded
+    dispatch splits every tile across the fleet and the budget is per
+    device, so the tile may hold the per-device frame cap on each of them.
     """
     if n_out is None:
         n_out = n_in
     bytes_per_frame = int(dtype_bytes * (n_in + n_out) * _INTERMEDIATE_FACTOR)
     cap = budget.tile_for(n_in, n_out, pipeline_depth=pipeline_depth,
                           dtype_bytes=dtype_bytes)
+    if cap is not None:
+        cap *= max(1, int(n_devices))
     if cap is None or cap >= k:
         tile = k
     else:
@@ -326,13 +334,17 @@ class BlockPlan:
         return (self.bb, self.bm, self.bk, self.bn)
 
 
-def _stage_block_bytes(bb: int, b: int, dtype_bytes: int = BYTES_F32) -> int:
-    """VMEM bytes one batched-DFT grid step holds at square block size
-    ``b`` with ``bb`` frames per step: two (b, b) factor blocks, a
-    (bb, b, b) operand block, (bb, b, b) of accumulator scratch x2, and a
-    (bb, b, b) output block (stage 2's is the widest; stage 1 writes two
-    outputs but reads one operand — same total)."""
-    return dtype_bytes * (2 * b * b + 4 * bb * b * b)
+def _stage_block_bytes(bb: int, bm: int, bk: int, bn: int,
+                       dtype_bytes: int = BYTES_F32) -> int:
+    """VMEM bytes one batched-DFT grid step holds, the larger of the two
+    stages: stage 1 holds two (bm, bk) factor blocks, a (bb, bk, bn)
+    operand block, and (bb, bm, bn) of (re, im) output plus (re, im)
+    accumulator scratch; stage 2 holds a (bb, bm, bk) (re, im) operand,
+    two (bn, bk) factor blocks, one (bb, bm, bn) output and the two
+    accumulators."""
+    stage1 = 2 * bm * bk + bb * bk * bn + 4 * bb * bm * bn
+    stage2 = 2 * bb * bm * bk + 2 * bn * bk + 3 * bb * bm * bn
+    return dtype_bytes * max(stage1, stage2)
 
 
 def choose_blocks(batch: int, m: int, k: int, n: int,
@@ -340,32 +352,33 @@ def choose_blocks(batch: int, m: int, k: int, n: int,
                   preferred: int = 128, max_bb: int = 8) -> BlockPlan:
     """Block sizes for one batched DFT stage from the VMEM budget.
 
-    Starts from the MXU-shaped ``preferred`` cube and halves until one
-    grid step's working set (:func:`_stage_block_bytes`) fits the
-    spendable budget; then grows ``bb`` (frames per grid step — they share
-    one load of the factor blocks) through the divisors of ``batch`` while
-    the footprint still fits, capped at ``max_bb`` to bound kernel unroll.
-    With no budget (None / unlimited) the classic ``pick_block`` defaults
-    come back unchanged (``bb=1``), so off-budget callers compile exactly
-    the kernels they always did.
+    The lane blocks ``bk``/``bn`` stay MXU-shaped: a TPU block's last
+    dimension must be a multiple of 128 or the whole dimension, so they
+    are never shrunk below ``preferred``.  The sublane block ``bm``
+    (multiples of 8) halves from ``preferred`` until one frame's grid step
+    (:func:`_stage_block_bytes`) fits the spendable budget — down to 8,
+    where it dispatches even if over — and then ``bb`` (frames per grid
+    step, sharing one load of the factor blocks) grows through the
+    divisors of ``batch`` while the footprint still fits, capped at
+    ``max_bb`` to bound kernel unroll.  So under pressure ``bb`` gives way
+    first and ``bm`` second.  With no budget (None / unlimited) the
+    classic ``pick_block`` defaults come back unchanged (``bb=1``), so
+    off-budget callers compile exactly the kernels they always did.
     """
     from repro.kernels.common import pick_block
 
-    def resolve(b: int) -> tuple[int, int, int]:
-        return (pick_block(m, b, 8), pick_block(k, b, 128),
-                pick_block(n, b, 128))
-
+    bk = pick_block(k, preferred, 128)
+    bn = pick_block(n, preferred, 128)
+    bm = pick_block(m, preferred, 8)
     if budget is None or budget.is_unlimited:
-        bm, bk, bn = resolve(preferred)
         return BlockPlan(bb=1, bm=bm, bk=bk, bn=bn)
     spend = budget.spendable_bytes
     b = preferred
-    while b > 8 and _stage_block_bytes(1, b) > spend:
+    while b > 8 and _stage_block_bytes(1, bm, bk, bn) > spend:
         b //= 2
-    bm, bk, bn = resolve(b)
-    side = max(bm, bk, bn)
+        bm = pick_block(m, b, 8)
     bb = 1
     for d in range(2, min(batch, max_bb) + 1):
-        if batch % d == 0 and _stage_block_bytes(d, side) <= spend:
+        if batch % d == 0 and _stage_block_bytes(d, bm, bk, bn) <= spend:
             bb = d
     return BlockPlan(bb=bb, bm=bm, bk=bk, bn=bn)

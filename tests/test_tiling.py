@@ -143,11 +143,14 @@ def test_choose_blocks_defaults_without_budget():
 
 
 def test_choose_blocks_shrinks_to_fit_and_grows_bb():
-    # a tight budget shrinks the cube below the MXU-preferred 128
+    # a tight budget shrinks the sublane block below the MXU-preferred
+    # 128; the lane blocks stay 128 (a TPU block's last dim must be a
+    # multiple of 128 or the whole dim), and bb gives way first
     tight = MemoryBudget(64 * 64 * 4 * 8, source="manual", reserve=1.0)
     plan = choose_blocks(16, 512, 512, 512, tight)
-    assert max(plan.bm, plan.bk, plan.bn) < 128
-    assert plan.bb >= 1
+    assert plan.bm < 128 and plan.bm % 8 == 0
+    assert (plan.bk, plan.bn) == (128, 128)
+    assert plan.bb == 1
     # an ample budget keeps the 128 cube and batches frames per grid step
     ample = MemoryBudget(16 * 1024 * 1024, source="manual", reserve=0.75)
     plan_a = choose_blocks(16, 512, 512, 512, ample)
@@ -385,7 +388,7 @@ def test_block_plan_cache_keys_by_stack_and_budget():
                                      reserve=1.0)
     tight = ex.ctx.blocks_for(16, 512, 512)           # budget change: fresh
     assert len(ex.ctx.block_cache) == 3
-    assert max(tight.bm, tight.bk, tight.bn) < 128
+    assert tight.bm < 128 and (tight.bk, tight.bn) == (128, 128)
 
 
 # --- telemetry ----------------------------------------------------------------
@@ -420,7 +423,9 @@ def test_choose_sharding_picks_budget_tile_and_respects_operator_pin():
     for im in _imgs(8, (16, 16)):
         router.run("fft", im)
     k, n, t = router.choose_sharding()["fft"]
-    assert k == 16 and t == 2        # the budget's pick, not the batch
+    # the budget's per-device pick (2) on each of the 4 devices a sharded
+    # tile spreads over, not the batch
+    assert k == 16 and n == 4 and t == 8
     router.replan()
     assert ex.category_tile_ks()["fft"] == t
     # an operator pin below the budget's choice is a bound the router keeps

@@ -640,8 +640,8 @@ def test_traced_tiled_sharded_flush_reconciles(tmp_path):
     reconcile with the measured flush wall to within 10% and join against
     the modeled decomposition per stage."""
     imgs = _imgs(8, shape=(512, 512))
-    # budget admits 4-frame tiles: the group streams as 2 sub-invocations,
-    # each scattered across 2 devices
+    # budget admits one frame per device: the group streams as 2-frame
+    # sub-invocations, each scattered across 2 devices
     budget = MemoryBudget(2 * 4 * 512 * 512 * 4, source="manual",
                           reserve=1.0)
     tracer = Tracer()
