@@ -76,16 +76,10 @@ def main() -> None:
     # continuous-batching column with its scheduler config — deadline,
     # arrival rate, seed — stamped alongside the measured occupancies) so
     # the perf trajectory is machine-readable AND interpretable across PRs.
-    # write_json also appends the record to BENCH_history.jsonl — the
-    # trajectory the traced column's drift gate bands against.
-    from benchmarks.runtime_bench import (drift_gate, load_history,
-                                          run as runtime_bench, write_json)
-    history = load_history()  # read before write_json appends this run
+    from benchmarks.runtime_bench import run as runtime_bench, write_json
     payload = write_json()
     for row in runtime_bench(payload):
         print(row)
-    ok, msg = drift_gate(payload["traced"]["drift"], history)
-    print(f"drift_gate,{'ok' if ok else 'FAIL'},,{msg}")
 
     # --- Roofline (needs dry-run artifacts) -------------------------------------------
     import os

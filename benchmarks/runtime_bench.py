@@ -34,11 +34,8 @@ Three claims, measured on the executing runtime (not just the cost model):
   executor actually dispatched.
 * **Traced column** — the opt-in span tracer re-runs the K-deep flush and
   reports (a) its own overhead vs the untraced executor (< 5% or the CI
-  smoke fails), (b) how much of the measured flush wall the per-stage
-  charged spans reconcile (coverage ~1), and (c) the boundary-stage drift
-  ratio (measured host staging / modeled DAC+interface), gated by
-  ``drift_gate`` against a static band plus the ``BENCH_history.jsonl``
-  median.
+  smoke fails) and (b) how much of the measured flush wall the per-stage
+  charged spans reconcile (coverage ~1).
 * **Chaos column** — the same offload traffic through a chaos-wrapped
   optical backend injecting a seeded fault mix (transient errors,
   stragglers, ENOB drift, device loss) at 0 / 1% / 10% per-dispatch
@@ -74,7 +71,6 @@ Run:  PYTHONPATH=src python -m benchmarks.runtime_bench
 from __future__ import annotations
 
 import dataclasses
-import datetime
 import json
 import time
 
@@ -92,7 +88,6 @@ from repro.runtime import (
     PlanRouter,
     Tracer,
     choose_tile,
-    drift_report,
     enob_error_bound,
     reconcile,
     register_chaos,
@@ -102,17 +97,6 @@ from repro.runtime import (
 SHAPE = (128, 128)
 CALLS = 16
 BENCH_JSON = "BENCH_runtime.json"
-BENCH_HISTORY = "BENCH_history.jsonl"
-
-# Tolerance band for the boundary-stage drift gate (measured host staging /
-# modeled DAC+interface price).  Below 1: the host stages frames cheaper
-# than the modeled optical boundary converts them — the headroom every
-# batching claim rests on.  Above 1 would mean the runtime's own dispatch
-# overhead exceeds the boundary cost it claims to amortize (the cost model
-# and reality have diverged in the claim-breaking direction); the low edge
-# catches a broken clock / empty measurement masquerading as speed.
-DRIFT_BAND = (0.005, 1.0)
-DRIFT_HISTORY_FACTOR = 4.0  # vs the median of prior runs, when >= 3 exist
 
 # Large-frame scenario: the regime where a monolithic (K, H, W) stack
 # falls out of the LLC off-TPU (ROADMAP's last open lever) and the
@@ -277,15 +261,14 @@ def sharded_comparison(shape: tuple[int, int] = SHAPE, calls: int = CALLS,
             base_wall, base_modeled = wall, modeled
         # attribution flush (satellite of the 0.71x investigation): rerun
         # the same group with a tracer attached so the row carries the
-        # per-device scatter-staging breakdown and the per-stage drift —
-        # the timed wall above stays untraced
+        # per-device scatter-staging breakdown — the timed wall above
+        # stays untraced
         tracer = Tracer()
         ex.tracer = ex.ctx.tracer = tracer
         for h in [ex.submit("fft", im) for im in imgs]:
             pass
         ex.flush()
         ex.tracer = ex.ctx.tracer = None
-        rep = drift_report(tracer.spans())
         scatter_s = _scatter_stage_s(tracer, calls)
 
         # resident column: same group, committed placement — the priming
@@ -356,7 +339,6 @@ def sharded_comparison(shape: tuple[int, int] = SHAPE, calls: int = CALLS,
             "per_engine_modeled_s_per_call": composed.total_s / (2 * mb),
             "devices_present": len(jax.devices()),
             "devices_used": ex.telemetry.devices_observed("fft"),
-            "trace": rep.to_json(),
         })
     return rows
 
@@ -364,17 +346,15 @@ def sharded_comparison(shape: tuple[int, int] = SHAPE, calls: int = CALLS,
 def traced_comparison(shape: tuple[int, int] = SHAPE, calls: int = CALLS,
                       trace_path: str | None = None) -> dict:
     """The observability column: what does attaching a tracer cost, and do
-    its spans reconcile with both the measured wall and the cost model?
+    its spans reconcile with the measured wall?
 
-    Three numbers, each a gate the CI smoke asserts:
+    Two numbers, each a gate the CI smoke asserts:
 
     * ``tracer_overhead`` — best-of-reps traced vs untraced K-deep flush
       wall (< 5%: tracing must be cheap enough to leave on in serving).
     * ``reconcile.coverage`` — per-stage charged sums (stage + compute +
       hold + shadow) over the measured accounting-flush wall (~1: the
       span decomposition accounts for the flush end to end).
-    * ``drift.stages.stage.drift`` — measured host staging vs the modeled
-      DAC+interface price (:func:`drift_gate`'s tolerance band).
 
     Pass ``trace_path`` to also write the Perfetto-loadable export (the CI
     trace artifact).
@@ -396,7 +376,6 @@ def traced_comparison(shape: tuple[int, int] = SHAPE, calls: int = CALLS,
     flush_wall = time.perf_counter() - t0
     spans = tracer.spans()
     rec = reconcile(spans, flush_wall)
-    rep = drift_report(spans)
     out = {
         "shape": list(shape),
         "calls": calls,
@@ -405,86 +384,11 @@ def traced_comparison(shape: tuple[int, int] = SHAPE, calls: int = CALLS,
         "tracer_overhead": traced / max(untraced, 1e-12) - 1.0,
         "spans": len(spans),
         "reconcile": rec,
-        "drift": rep.to_json(),
     }
     if trace_path:
         write_trace(trace_path, spans)
         out["trace_path"] = trace_path
     return out
-
-
-def drift_gate(drift: dict, history: list[dict] | None = None,
-               band: tuple[float, float] = DRIFT_BAND,
-               history_factor: float = DRIFT_HISTORY_FACTOR,
-               ) -> tuple[bool, str]:
-    """The regression gate over the boundary stage's drift ratio.
-
-    ``drift`` is a ``DriftReport.to_json()`` dict.  Passes when the
-    boundary ("stage") drift is inside ``band`` — and, when ``history``
-    (prior ``BENCH_history.jsonl`` records) holds at least 3 prior traced
-    runs, within ``history_factor`` of their median, so a slow machine-
-    local regression trips even inside the static band.
-    """
-    stage = drift.get("stages", {}).get("stage", {})
-    d = stage.get("drift")
-    if d is None or d == "inf":
-        return False, f"boundary stage drift unmeasurable: {stage!r}"
-    d = float(d)
-    lo, hi = band
-    if not lo <= d <= hi:
-        return False, (f"boundary stage drift {d:.4f} outside tolerance "
-                       f"band [{lo}, {hi}] — cost model and measured "
-                       f"staging have diverged")
-    prior = []
-    for rec in history or []:
-        try:
-            p = rec["traced"]["drift"]["stages"]["stage"]["drift"]
-        except (KeyError, TypeError):
-            continue
-        if isinstance(p, (int, float)):
-            prior.append(float(p))
-    if len(prior) >= 3:
-        med = sorted(prior)[len(prior) // 2]
-        if not med / history_factor <= d <= med * history_factor:
-            return False, (f"boundary stage drift {d:.4f} is more than "
-                           f"{history_factor}x away from the history "
-                           f"median {med:.4f} ({len(prior)} prior runs)")
-        return True, (f"boundary stage drift {d:.4f} within band {band} "
-                      f"and {history_factor}x of history median {med:.4f}")
-    return True, f"boundary stage drift {d:.4f} within band {band}"
-
-
-def load_history(path: str = BENCH_HISTORY) -> list[dict]:
-    """Prior bench records, oldest first (empty when no history yet)."""
-    try:
-        with open(path) as f:
-            lines = f.readlines()
-    except FileNotFoundError:
-        return []
-    out = []
-    for line in lines:
-        line = line.strip()
-        if not line:
-            continue
-        try:
-            out.append(json.loads(line))
-        except json.JSONDecodeError:
-            continue
-    return out
-
-
-def append_history(payload: dict, path: str = BENCH_HISTORY) -> dict:
-    """Append one timestamped record to the bench trajectory.
-
-    ``BENCH_runtime.json`` is overwritten in place on every run, so on its
-    own the repo holds no perf *trajectory*; this JSONL keeps every run
-    (UTC-stamped), which is what the drift gate's history band and any
-    cross-PR perf question read."""
-    rec = dict(ts=datetime.datetime.now(datetime.timezone.utc)
-               .isoformat(timespec="seconds"), **payload)
-    with open(path, "a") as f:
-        f.write(json.dumps(rec, default=str) + "\n")
-    return rec
 
 
 def large_frame_comparison(shape: tuple[int, int] = LARGE_SHAPE,
@@ -740,9 +644,7 @@ def residency_comparison(shape: tuple[int, int] = SHAPE, calls: int = CALLS,
 
     The CI smoke asserts hit < delta < restage on the measured wall,
     that the modeled delta DAC time sits strictly between zero and the
-    restage price, and that both cached paths retire bit-equal to plain;
-    the row lands in ``BENCH_history.jsonl`` so the PR 6 drift gate
-    covers the cached paths' trajectories too.
+    restage price, and that both cached paths retire bit-equal to plain.
     """
     def _conv_kernel():
         h, w = shape
@@ -897,10 +799,6 @@ def write_json(path: str = BENCH_JSON) -> dict:
     payload = bench_payload()
     with open(path, "w") as f:
         json.dump(payload, f, indent=2, default=str)
-    # BENCH_runtime.json is a snapshot; the JSONL keeps the trajectory the
-    # drift gate's history band reads (both main() and benchmarks/run.py
-    # land here, so each bench run is recorded exactly once).
-    append_history(payload)
     return payload
 
 
@@ -965,15 +863,11 @@ def run(payload: dict | None = None) -> list[str]:
         f"|budget={lf['budget_bytes'] // (1024 * 1024)}MiB"
         f"({lf['budget_source']})")
     tc = payload["traced"]
-    stage_drift = tc["drift"]["stages"].get("stage", {}).get("drift")
-    stage_txt = (f"{stage_drift:.3f}"
-                 if isinstance(stage_drift, (int, float)) else "n/a")
     rows.append(
         f"runtime,traced,{1e6 * tc['traced_wall_s_per_call']:.1f},"
         f"tracer_overhead={100 * tc['tracer_overhead']:.1f}%"
         f"|untraced={1e6 * tc['untraced_wall_s_per_call']:.1f}us"
         f"|coverage={tc['reconcile']['coverage']:.2f}"
-        f"|stage_drift={stage_txt}"
         f"|spans={tc['spans']}")
     for r in payload["chaos"]["rows"]:
         rec = r["recovery"] or {}
@@ -1017,13 +911,10 @@ def run(payload: dict | None = None) -> list[str]:
 
 
 def main() -> None:
-    history = load_history()  # read before write_json appends this run
     payload = write_json()
     print("section,name,us_per_call,derived")
     for row in run(payload):
         print(row)
-    ok, msg = drift_gate(payload["traced"]["drift"], history)
-    print(f"drift_gate,{'ok' if ok else 'FAIL'},,{msg}")
 
 
 if __name__ == "__main__":

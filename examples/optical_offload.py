@@ -43,10 +43,8 @@ batching story prices it:
   8. observe   — attach the opt-in span tracer and re-run the conv
                  workload: one span tree per batched invocation
                  (submit -> release -> stage -> compute -> shadow), a
-                 one-screen trace digest, wall percentiles per category,
-                 and the modeled-vs-measured drift table that names the
-                 stage where the cost model and the wall clock disagree
-                 most.
+                 one-screen trace digest and wall percentiles per
+                 category.
   9. survive   — wrap the optical backend in a seeded ``ChaosBackend``
                  (10% of dispatches fault: transient errors, stragglers,
                  ENOB drift, device loss) and serve the same frames: the
@@ -88,7 +86,6 @@ from repro.runtime import (
     OffloadScheduler,
     PlanRouter,
     Tracer,
-    drift_report,
     enob_error_bound,
     register_chaos,
     summarize,
@@ -333,8 +330,9 @@ def run_traced_demo(imgs, kernels) -> None:
     # span tree — submit instants on the sched lane, the release that
     # dispatched it, the charged host staging (DAC-side) span, the charged
     # device compute (analog+ADC) span, the fidelity shadow — annotated
-    # with the modeled batched_step_cost decomposition, so the drift
-    # report can name the stage where model and wall clock disagree.
+    # with the modeled batched_step_cost decomposition.  Under
+    # jax.profiler the lexical spans also land in the profiler's trace
+    # as repro.<name> host annotations, beside the device's programs.
     tracer = Tracer()
     with OffloadExecutor(BATCHED_4F, max_batch=16, tracer=tracer,
                          mem_budget=MemoryBudget.unlimited()) as ex:
@@ -348,8 +346,6 @@ def run_traced_demo(imgs, kernels) -> None:
         pct = ex.telemetry.percentiles("conv")
         print("conv wall percentiles: " + "  ".join(
             f"p{int(p)}={v * 1e3:.2f}ms" for p, v in pct.items()))
-        print("\nmodeled-vs-measured drift (per stage):")
-        print(drift_report(tracer.spans()).table())
 
 
 def run_chaos_demo(calls: int = 32, rate: float = 0.10) -> None:

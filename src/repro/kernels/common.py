@@ -9,7 +9,8 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["INTERPRET", "MXU", "LANE", "SUBLANE", "round_up", "pick_block"]
+__all__ = ["INTERPRET", "MXU", "LANE", "SUBLANE", "round_up", "pick_block",
+           "program_name"]
 
 INTERPRET = jax.default_backend() != "tpu"
 
@@ -38,3 +39,14 @@ def pick_block(dim: int, preferred: int, align: int) -> int:
             return b
         b -= align
     return dim
+
+
+def program_name(name: str):
+    """Decorator: ``jax.jit`` of the decorated function makes a program
+    named ``jit_<name>``, whatever the function itself is called.  Profiles
+    list device time by program name, and the benchmark's roofline tables
+    find their programs by it, so a rename must not change it silently."""
+    def wrap(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+    return wrap

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import INTERPRET, pick_block
+from repro.kernels.common import INTERPRET, pick_block, program_name
 
 __all__ = [
     "dft_matrix_factors",
@@ -146,6 +146,7 @@ def _stage1_batched_kernel(wr_ref, wi_ref, a_ref, tr_ref, ti_ref, acc_r, acc_i,
 
 @functools.partial(jax.jit, static_argnames=("dac_bits", "bb", "bm", "bk",
                                              "bn"))
+@program_name("dft_stage1_batched")
 def dft_stage1_batched(wr: jax.Array, wi: jax.Array, a: jax.Array, *,
                        dac_bits: int = 0, bb: int = 1, bm: int = 128,
                        bk: int = 128, bn: int = 128):
@@ -283,6 +284,7 @@ def _stage2_batched_kernel(tr_ref, ti_ref, wr_ref, wi_ref, out_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("bb", "bm", "bk", "bn"))
+@program_name("dft_stage2_batched")
 def dft_stage2_batched(tr: jax.Array, ti: jax.Array, wr: jax.Array,
                        wi: jax.Array, *, bb: int = 1, bm: int = 128,
                        bk: int = 128, bn: int = 128):

@@ -115,10 +115,7 @@ that runs it.  Module map:
                sharded dispatch.  Zero overhead when off; injectable
                clock (``ManualClock``) for exact test assertions.
   metrics    — ``Counter`` / ``Histogram`` / ``MetricsRegistry``
-               (mergeable log-binned percentile histograms) and
-               ``drift_report``: the modeled-vs-measured per-stage join
-               against ``batched_step_cost`` that names the
-               worst-drifting stage.
+               (mergeable log-binned percentile histograms).
   trace_export — Chrome/Perfetto ``trace_event`` JSON export
                (``write_trace``), per-stage charged sums
                (``stage_sums`` / ``reconcile``), one-screen digests
@@ -167,11 +164,8 @@ from repro.runtime.faults import (
 from repro.runtime.fidelity import FidelityChecker, FidelityReport, enob_error_bound
 from repro.runtime.metrics import (
     Counter,
-    DriftReport,
     Histogram,
     MetricsRegistry,
-    StageDrift,
-    drift_report,
 )
 from repro.runtime.residency import (
     DELTA_THRESHOLD,
@@ -262,11 +256,8 @@ __all__ = [
     "CAMERA_ADC",
     "SLM_DAC",
     "Counter",
-    "DriftReport",
     "Histogram",
     "MetricsRegistry",
-    "StageDrift",
-    "drift_report",
     "Span",
     "Tracer",
     "reconcile",

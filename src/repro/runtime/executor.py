@@ -44,6 +44,7 @@ batches retire synchronously — validation mode trades the pipeline away).
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import time
 from typing import Any, Callable, Mapping
@@ -682,8 +683,12 @@ class OffloadExecutor:
         groups still overlap in flight while the flush proceeds, but by
         return time every result is concrete and recorded.
         """
-        done = self.flush_async()
-        self.drain()
+        scope = contextlib.nullcontext()
+        if self.tracer is not None:
+            scope = self.tracer.span("flush", lane="sched")
+        with scope:
+            done = self.flush_async()
+            self.drain()
         return done
 
     def pending_groups(self) -> dict[tuple, list[_Pending]]:
@@ -725,19 +730,19 @@ class OffloadExecutor:
         chosen = set(map(id, members))
         self._queue = [p for p in self._queue if id(p) not in chosen]
         tr = self.tracer
-        rel = None
+        scope = contextlib.nullcontext()
         if tr is not None:
-            rel = tr.begin("release", lane="sched", reason=reason,
-                           category=members[0].category, count=len(members))
+            scope = tr.span("release", lane="sched", kind="async",
+                            reason=reason, category=members[0].category,
+                            count=len(members))
             tr.metrics.counter("release", reason=reason).inc()
         done: list[OffloadResult] = []
         cap = self.max_batch_for(members[0].category)
-        for i in range(0, len(members), cap):
-            chunk = members[i:i + cap]
-            self._dispatch_async(chunk, reason=reason, parent=rel)
-            done.extend(p.result for p in chunk)
-        if rel is not None:
-            tr.end(rel)
+        with scope as rel:
+            for i in range(0, len(members), cap):
+                chunk = members[i:i + cap]
+                self._dispatch_async(chunk, reason=reason, parent=rel)
+                done.extend(p.result for p in chunk)
         return done
 
     def flush_async(self) -> list[OffloadResult]:
@@ -1057,8 +1062,8 @@ class OffloadExecutor:
             modeled = dataclasses.replace(
                 modeled, hold_s=modeled.hold_s + hold_s)
         if inv is not None and modeled is not None:
-            # the decomposition the drift report joins measured spans
-            # against — the exact batched_step_cost the planner priced
+            # the exact batched_step_cost the planner priced, beside the
+            # measured decomposition in the exported trace
             inv.annotate(modeled_dac_s=modeled.dac_s,
                          modeled_adc_s=modeled.adc_s,
                          modeled_interface_s=modeled.interface_s,
@@ -1132,7 +1137,7 @@ class OffloadExecutor:
             # share of the charged wall, compute the in-flight remainder —
             # so stage + compute == wall exactly, pipeline overlap is never
             # billed twice, and per-stage sums reconcile with the flush's
-            # measured wall (the export/drift contract).  Deferred
+            # measured wall (the export contract).  Deferred
             # retirement (wall == dispatch_s) yields a zero-length compute
             # span: the device window elapsed under someone else's clock.
             stage_charged = min(f.dispatch_s, wall)
@@ -1152,30 +1157,32 @@ class OffloadExecutor:
         report = None
         if f.shadow:
             t1 = time.perf_counter()
-            sh = None
-            if tr is not None and f.span is not None:
-                sh = tr.begin("fidelity-shadow", lane="host", kind="sync",
-                              parent=f.span, category=f.chunk[0].category)
-            # the shadow reference is a validation probe, not workload:
-            # it must neither serve from nor populate the residency cache,
-            # or shadow traffic would inflate hit rates and evict operands
-            # the real dispatch path still needs
-            saved_res, self.ctx.residency = self.ctx.residency, None
-            try:
-                refs, _ = self._backend("host").run(
-                    f.chunk[0].category, [p.x for p in f.chunk], self.ctx,
-                    kernel=f.chunk[0].kernel, weights=f.chunk[0].weights)
-                _block(refs)
-            finally:
-                self.ctx.residency = saved_res
-            spec = self.ctx.spec
-            enob = min(spec.dac.effective_bits, spec.adc.effective_bits)
-            report = self.fidelity.check(f.chunk[0].category, f.be.name,
-                                         f.outs, refs, enob=enob)
+            traced = tr is not None and f.span is not None
+            scope = contextlib.nullcontext()
+            if traced:
+                scope = tr.span("fidelity-shadow", lane="host",
+                                parent=f.span, category=f.chunk[0].category)
+            with scope:
+                # the shadow reference is a validation probe, not
+                # workload: it must neither serve from nor populate the
+                # residency cache, or shadow traffic would inflate hit
+                # rates and evict operands the real dispatch path needs
+                saved_res, self.ctx.residency = self.ctx.residency, None
+                try:
+                    refs, _ = self._backend("host").run(
+                        f.chunk[0].category, [p.x for p in f.chunk],
+                        self.ctx, kernel=f.chunk[0].kernel,
+                        weights=f.chunk[0].weights)
+                    _block(refs)
+                finally:
+                    self.ctx.residency = saved_res
+                spec = self.ctx.spec
+                enob = min(spec.dac.effective_bits, spec.adc.effective_bits)
+                report = self.fidelity.check(f.chunk[0].category, f.be.name,
+                                             f.outs, refs, enob=enob)
             # validation overhead, not workload: keep it out of 'other'
             dt = time.perf_counter() - t1
-            if sh is not None:
-                tr.end(sh)
+            if traced:
                 f.span.annotate(shadow_s=dt)
             self.telemetry.discount_window(dt)
             self._last_retire_end += dt

@@ -24,7 +24,6 @@ from repro.core.conversion import delta_write_scale
 from repro.core.planner import CategoryProfile, OffloadPlan, plan_offload
 from repro.runtime.backends import CATEGORIES, CONV_CAPTURES
 from repro.runtime.executor import OffloadExecutor, OffloadResult
-from repro.runtime.metrics import DriftReport, drift_report
 
 __all__ = ["PlanRouter"]
 
@@ -56,9 +55,6 @@ class PlanRouter:
         self._router_set_tile: dict[str, int] = {}
         self._operator_window_caps: dict[str, int] = {}
         self._router_set_window: dict[str, int] = {}
-        # modeled-vs-measured attribution from the executor's tracer,
-        # refreshed by each replan (None when tracing is off / no spans)
-        self.drift: DriftReport | None = None
         if plan is not None:
             self.apply(plan)
 
@@ -334,13 +330,6 @@ class PlanRouter:
         without touching the routing table or the executor's ceilings.
         """
         telemetry = self.executor.telemetry
-        tracer = getattr(self.executor, "tracer", None)
-        if tracer is not None:
-            # modeled-vs-measured attribution for the traffic this replan
-            # prices: the worst-drifting stage names where the cost model
-            # and the measured runtime disagree most
-            rep = drift_report(tracer.spans())
-            self.drift = rep if rep.invocations else None
         profiles = list(telemetry.profiles())
         profiles.extend(extra_profiles)
         checker = self.executor.fidelity
@@ -385,12 +374,6 @@ class PlanRouter:
     def summary(self) -> str:
         rows = ["router: " + ", ".join(
             f"{c}->{b}" for c, b in sorted(self.routes.items()))]
-        if self.drift is not None and self.drift.worst is not None:
-            w = self.drift.worst
-            rows.append(
-                f"  drift: worst stage '{w.stage}' measured/modeled="
-                f"{w.drift:.3g} over {self.drift.invocations} traced "
-                f"invocations")
         if self.plan is not None:
             rows.append(self.plan.summary())
         return "\n".join(rows)

@@ -25,15 +25,27 @@ exclusion window.  The operand residency cache
 (kind = hit / miss / eviction / invalidation, with the operand category
 and byte count), so every boundary crossing the cache *avoided* is as
 visible as the ones that were paid.  None of these carry charged time —
-the reconcile / drift contract reads only ``invocation`` trees — so
-fault and cache observability can never unbalance the wall accounting.
+the reconcile contract reads only ``invocation`` trees — so fault and
+cache observability can never unbalance the wall accounting.
+
+Every lexical span (:meth:`Tracer.span`: the executor's ``flush`` around
+a blocking flush, ``release``, ``stage``, ``fidelity-shadow``, the sharded
+``scatter``/``scatter_stage``/``gather``, the serving engine's
+``engine.*``) also opens a profiler host annotation named
+``repro.<name>``, so under ``jax.profiler`` the span
+lands on the ``/host:CPU`` plane of the same trace as the device's
+programs, on one clock: an idle gap on the device can be put down to the
+program span that covers it.  Retrospective spans (:meth:`Tracer.record`:
+``compute``, ``held``, ``quarantine``, ``queued``) and ``begin``/``end``
+containers stay on the tracer only.
 
 Design constraints (all load-bearing):
 
-* **Zero dependencies, zero default overhead.**  Tracing is opt-in
+* **Zero default overhead.**  Tracing is opt-in
   (``OffloadExecutor(tracer=...)``); every instrumentation site guards on
   ``tracer is not None``, so the default path adds nothing but an
-  attribute read.
+  attribute read.  With a tracer attached and no profiler running, a
+  span's annotation costs well under a microsecond.
 * **Injectable clock.**  ``Tracer(clock=ManualClock())`` shares the
   executor's manual timebase, so tests assert span durations *exactly*
   (a group held 30 ms under a ManualClock yields a held span of exactly
@@ -50,9 +62,9 @@ Design constraints (all load-bearing):
   and the Perfetto export both rely on.
 
 Consumers: :mod:`repro.runtime.trace_export` (Chrome/Perfetto
-``trace_event`` JSON), :func:`repro.runtime.metrics.drift_report`
-(modeled-vs-measured per stage), and the trace summary printed by
-``examples/optical_offload.py``.
+``trace_event`` JSON and per-stage charged sums), the trace summary
+printed by ``examples/optical_offload.py``, and a ``jax.profiler`` trace
+of the process, where the annotations sit beside the device's programs.
 """
 
 from __future__ import annotations
@@ -64,6 +76,8 @@ import itertools
 import threading
 import time
 from typing import Any, Callable, Iterator
+
+from jax.profiler import TraceAnnotation
 
 from repro.runtime.metrics import MetricsRegistry
 
@@ -92,6 +106,9 @@ class Span:
     t0: float = 0.0
     t1: float | None = None
     attrs: dict[str, Any] = dataclasses.field(default_factory=dict)
+    # True for a lexical span, which also opened the profiler annotation
+    # ``repro.<name>`` and so lies on the device trace's timeline
+    annotated: bool = False
 
     @property
     def duration_s(self) -> float:
@@ -193,15 +210,18 @@ class Tracer:
     def span(self, name: str, *, lane: str = "host", kind: str = "sync",
              parent: "Span | int | None" = None,
              **attrs: Any) -> Iterator[Span]:
-        """Lexically scoped span; children opened inside nest under it."""
-        s = self._new(name, lane, kind, self.now(), parent, attrs)
-        st = self._stack()
-        st.append(s)
-        try:
-            yield s
-        finally:
-            st.pop()
-            self.end(s)
+        """Lexically scoped span; children opened inside nest under it.
+        The profiler annotation ``repro.<name>`` covers the same scope."""
+        with TraceAnnotation(f"repro.{name}"):
+            s = self._new(name, lane, kind, self.now(), parent, attrs)
+            s.annotated = True
+            st = self._stack()
+            st.append(s)
+            try:
+                yield s
+            finally:
+                st.pop()
+                self.end(s)
 
     def instant(self, name: str, *, lane: str = "host",
                 parent: "Span | int | None" = None, **attrs: Any) -> Span:
