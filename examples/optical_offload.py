@@ -35,7 +35,8 @@ batching story prices it:
                  queueing delay that buys it is priced (``StepCost.hold_s``).
   7. tile      — large frames under a memory budget: at 512x512 the
                  monolithic stacked flush group overflows the LLC
-                 (VMEM on TPU), so ``replan`` picks a sub-group ``tile_k``
+                 (on TPU it fits the HBM share whole), so ``replan``
+                 picks a sub-group ``tile_k``
                  from the detected byte budget and the released group
                  streams as tile-sized sub-invocations through the same
                  two-deep pipeline — amortization per tile, cache-resident
@@ -276,7 +277,7 @@ def run_tiled_demo(imgs) -> None:
     # A 512x512 K=8 flush group's monolithic stack (frames + complex
     # intermediates + results) falls out of the CPU's last-level cache
     # off-TPU — the regime where batching measurably loses to looping.
-    # The executor's memory budget (LLC-derived here, VMEM-derived on
+    # The executor's memory budget (LLC-derived here, a share of HBM on
     # TPU) makes replan pick a sub-group tile_k: the released group
     # streams as budget-sized sub-invocations through the same two-deep
     # pipeline, each tile's staging overlapped with the previous tile's

@@ -66,11 +66,11 @@ that runs it.  Module map:
   tiling     — ``MemoryBudget`` / ``choose_tile`` / ``choose_blocks``:
                memory-budgeted tiled dispatch.  A released flush group
                whose monolithic ``(K, H, W)`` stack would overflow the
-               per-device staging budget (VMEM-derived on TPU,
+               per-device staging budget (a share of HBM on TPU,
                LLC-derived off it) streams as ``ceil(K / tile_k)``
                sub-invocations through the same two-deep pipeline
                (write/analog/read overlap between tiles), and the batched
-               Pallas DFT grid's block sizes are derived from the same
+               Pallas DFT grid's block sizes are derived from a VMEM
                budget.  ``tile_k=1`` degenerates to looped, ``>= K`` to
                monolithic — the runtime-equivalence invariant covers all
                three.

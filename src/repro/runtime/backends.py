@@ -125,9 +125,10 @@ class BackendContext:
     automatic policy (see ``repro.runtime.sharded``).
 
     ``mem_budget`` is the per-device staging byte budget
-    (``repro.runtime.tiling.MemoryBudget``): the executor tiles flush
-    groups against it, and the optical backend derives the batched Pallas
-    grid's block sizes from it (``blocks_for``)."""
+    (``repro.runtime.tiling.MemoryBudget``): the memory flush-group stacks
+    are allocated in, HBM on TPU.  The executor tiles flush groups against
+    it.  The batched Pallas grid's block sizes (``blocks_for``) are sized
+    against ``block_budget`` instead, the VMEM one grid step lives in."""
 
     spec: OpticalFourierAcceleratorSpec | OpticalMVMAcceleratorSpec
     factor_cache: dict[tuple, tuple[jax.Array, jax.Array]] = \
@@ -180,15 +181,26 @@ class BackendContext:
     # staged codes.
     stage_stream: "object" = "host"
 
+    @property
+    def block_budget(self) -> "MemoryBudget | None":
+        """The budget one Pallas grid step is sized against: a TPU core's
+        VMEM when the staging budget models HBM, otherwise the staging
+        budget itself (the VMEM fallback, an off-TPU cache, an operator's
+        pin, or none)."""
+        if self.mem_budget is not None and self.mem_budget.source == "hbm":
+            return MemoryBudget.vmem()
+        return self.mem_budget
+
     def blocks_for(self, batch: int, h: int, w: int) -> "BlockPlan":
         """Resolved Pallas block sizes for a ``(batch, h, w)`` stacked DFT
-        invocation, derived from the VMEM budget (``choose_blocks``).
+        invocation, derived from ``block_budget`` (``choose_blocks``).
 
-        Keyed by the stack shape AND the budget's identity: replanning
-        ``tile_k`` changes the dispatched stack depth, and an operator
-        swapping the budget changes the blocks — either way the resolution
-        must be fresh, never a stale plan shaped for the old layout."""
-        budget = self.mem_budget
+        Keyed by the stack shape AND the budget it was derived from:
+        replanning ``tile_k`` changes the dispatched stack depth, and an
+        operator swapping the budget changes the blocks — either way the
+        resolution must be fresh, never a stale plan shaped for the old
+        layout."""
+        budget = self.block_budget
         key = (batch, h, w,
                None if budget is None else (budget.bytes_limit,
                                             budget.reserve))

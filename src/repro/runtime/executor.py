@@ -232,11 +232,15 @@ class OffloadExecutor:
       shard_mode: the sharded backend's split policy (``auto`` / ``group``
         / ``frame`` — see ``repro.runtime.sharded``).
       mem_budget: per-device staging byte budget
-        (:class:`~repro.runtime.tiling.MemoryBudget`).  ``None`` (default)
-        auto-detects: VMEM-derived on TPU, LLC-derived off it.  A released
-        group whose monolithic ``(K, H, W)`` stack would overflow the
-        budget streams as ``ceil(K / tile_k)`` budget-sized sub-invocations
-        through the two-deep pipeline instead (``choose_tile``); pass
+        (:class:`~repro.runtime.tiling.MemoryBudget`), a budget of the
+        memory flush-group stacks are allocated in.  ``None`` (default)
+        auto-detects: a fixed share of the chip's HBM on TPU (the VMEM
+        budget where the device reports no HBM limit), LLC-derived off it.
+        The Pallas grid's blocks are sized against VMEM separately
+        (``BackendContext.block_budget``).  A released group whose
+        monolithic ``(K, H, W)`` stack would overflow the budget streams as
+        ``ceil(K / tile_k)`` budget-sized sub-invocations through the
+        two-deep pipeline instead (``choose_tile``); pass
         ``MemoryBudget.unlimited()`` to restore monolithic dispatch.
       tile_k: explicit frames-per-tile override (global; per-category
         overrides via ``set_tile_k``).  ``None`` derives it from

@@ -7,14 +7,15 @@ boundary: a ``(K, H, W)`` flush group materializes K frames plus the
 pipeline's complex intermediates before anything crosses the DAC.  At
 128x128 that working set is noise; at 512x512 and K=16 it is ~64 MB — it
 falls out of the CPU's last-level cache off-TPU (a monolithic batched FFT
-measures *slower* than a Python loop of singles) and exceeds a TPU core's
-~16 MB VMEM budget on-chip.  The photonic case studies make the same
-point from the hardware side: sustained throughput is set by how operands
-are *staged* into the analog aperture, not by the transform itself.
+measures *slower* than a Python loop of singles).  On TPU the stack lives
+in HBM, beside whatever model shares the chip.  The photonic case studies
+make the same point from the hardware side: sustained throughput is set by
+how operands are *staged* into the analog aperture, not by the transform
+itself.
 
 This module decides the staging granularity from a per-device byte budget:
 
-  :class:`MemoryBudget`   where the bytes come from — VMEM-derived on TPU,
+  :class:`MemoryBudget`   where the bytes come from — HBM-derived on TPU,
                           LLC-derived off-TPU, or operator-pinned — and how
                           many frames of a given working set fit inside it.
   :func:`choose_tile`     pick ``tile_k``: the deepest sub-stack whose
@@ -26,8 +27,9 @@ This module decides the staging granularity from a per-device byte budget:
                           sub-invocations with write/analog/read overlap
                           *between* tiles, instead of one monolithic stack.
   :func:`choose_blocks`   pick the batched Pallas DFT grid's block sizes
-                          ``(bb, bm, bk, bn)`` from the VMEM budget instead
-                          of the fixed 128-cube defaults.
+                          ``(bb, bm, bk, bn)`` from a VMEM budget
+                          (:meth:`MemoryBudget.vmem` on TPU) instead of
+                          the fixed 128-cube defaults.
 
 ``tile_k = 1`` degenerates to the looped regime (one call per crossing),
 ``tile_k >= K`` to the monolithic one — both are valid points on the same
@@ -57,6 +59,7 @@ from repro.core.accelerator import tile_sizes
 __all__ = [
     "BYTES_F32",
     "TPU_VMEM_BYTES",
+    "HBM_STAGING_SHARE",
     "LLC_FALLBACK_BYTES",
     "MemoryBudget",
     "TilePlan",
@@ -68,10 +71,18 @@ __all__ = [
 
 BYTES_F32 = 4
 
-# A TPU core's on-chip vector memory (the Pallas guide's ~16 MB/core): the
-# stack, the (re, im) stage-1 intermediates, and the accumulator scratch
-# all want to live here while a batched DFT invocation runs.
+# A TPU core's on-chip vector memory (the Pallas guide's ~16 MB/core): one
+# batched-DFT grid step's operand, factor and output blocks and its
+# accumulator scratch live here (``choose_blocks``).
 TPU_VMEM_BYTES = 16 * 1024 * 1024
+
+# The share of a TPU's HBM ``bytes_limit`` that staging stacks may spend.
+# One eighth (2.1 GB of a v5e's 16.9 GB) leaves the rest to a co-resident
+# model: a served 1.6B model with its KV cache peaks near 87% of such a
+# chip, and the executor is built before it.  At the full 1024x768
+# aperture the share still holds 83 frames at pipeline depth 2, more than
+# the default ``max_batch`` of 32, so a released group stages whole.
+HBM_STAGING_SHARE = 0.125
 
 # Off-TPU fallback when the platform exposes no cache topology: a
 # mainstream server LLC.  Detection prefers the real number (sysfs /
@@ -127,17 +138,27 @@ def _llc_bytes() -> int:
     return LLC_FALLBACK_BYTES
 
 
+def _hbm_bytes_limit(device) -> int:
+    """The device's allocatable HBM (``memory_stats()["bytes_limit"]``), or
+    0 where the runtime does not report it."""
+    stats = getattr(device, "memory_stats", lambda: None)()
+    return int((stats or {}).get("bytes_limit", 0))
+
+
 @dataclasses.dataclass(frozen=True)
 class MemoryBudget:
-    """A per-device byte budget for staging batched operand stacks.
+    """A per-device byte budget of one memory: the pool batched operand
+    stacks are staged in (HBM on TPU), or the fast memory one Pallas grid
+    step is sized against (VMEM, :meth:`vmem`).
 
     Attributes:
       bytes_limit: total budgeted bytes; ``0`` (or negative) means
         *unlimited* — tiling is disabled and every group dispatches
         monolithically, the pre-tiling behavior.
-      source: where the number came from (``"vmem"`` / ``"llc"`` /
-        ``"manual"`` / ``"unlimited"``) — stamped into benchmarks so a
-        recorded ``tile_k`` stays interpretable across machines.
+      source: where the number came from (``"hbm"`` / ``"vmem"`` /
+        ``"llc"`` / ``"manual"`` / ``"unlimited"``) — stamped into
+        benchmarks so a recorded ``tile_k`` stays interpretable across
+        machines.
       reserve: fraction of ``bytes_limit`` actually spendable on operand
         staging.  The rest is headroom for everything the model does not
         count — XLA temporaries, the host program, other cores sharing the
@@ -153,23 +174,41 @@ class MemoryBudget:
             raise ValueError("reserve must be in (0, 1]")
 
     @classmethod
-    def detect(cls, platform: str | None = None) -> "MemoryBudget":
-        """The platform's budget: VMEM-derived on TPU, LLC-derived off it.
+    def detect(cls, platform: str | None = None,
+               device=None) -> "MemoryBudget":
+        """The platform's staging budget: HBM-derived on TPU, LLC-derived
+        off it.
 
-        On TPU the binding constraint is the ~16 MB/core VMEM the batched
-        Pallas pipeline tiles through (reserve 0.75: block scratch is
-        already counted, only compiler temporaries need headroom).  Off
-        TPU it is the last-level cache — a batched stack larger than the
-        LLC turns every XLA pass over it into a DRAM stream, which is
+        On TPU a flush group's ``(K, H, W)`` stack and its intermediates
+        are HBM allocations; only one Pallas grid step lives in VMEM, and
+        :meth:`vmem` budgets that separately.  The budget is
+        :data:`HBM_STAGING_SHARE` of ``device.memory_stats()["bytes_limit"]``
+        (``device`` defaults to ``jax.devices()[0]``), fixed here once: a
+        tile that followed ``bytes_in_use`` would compile new stack shapes
+        whenever other allocations came and went.  A device that reports no
+        ``bytes_limit`` falls back to :meth:`vmem`.  Off TPU the binding
+        constraint is the last-level cache — a batched stack larger than
+        the LLC turns every XLA pass over it into a DRAM stream, which is
         precisely where monolithic batching measures slower than looping
         (reserve 0.5: the LLC is shared with everything else on the host).
         """
+        import jax
         if platform is None:
-            import jax
             platform = jax.default_backend()
-        if platform == "tpu":
-            return cls(TPU_VMEM_BYTES, source="vmem", reserve=0.75)
-        return cls(_llc_bytes(), source="llc", reserve=0.5)
+        if platform != "tpu":
+            return cls(_llc_bytes(), source="llc", reserve=0.5)
+        hbm = _hbm_bytes_limit(jax.devices()[0] if device is None
+                               else device)
+        if hbm > 0:
+            return cls(hbm, source="hbm", reserve=HBM_STAGING_SHARE)
+        return cls.vmem()
+
+    @classmethod
+    def vmem(cls) -> "MemoryBudget":
+        """A TPU core's VMEM, the budget one batched-DFT grid step is sized
+        against (:func:`choose_blocks`; reserve 0.75: block scratch is
+        already counted, only compiler temporaries need headroom)."""
+        return cls(TPU_VMEM_BYTES, source="vmem", reserve=0.75)
 
     @classmethod
     def unlimited(cls) -> "MemoryBudget":

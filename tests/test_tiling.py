@@ -22,6 +22,7 @@ from repro.core.accelerator import ANDERSON_MVM, PROTOTYPE_4F
 from repro.core.conversion import ConverterSpec
 from repro.runtime import (
     BATCHED_4F,
+    FidelityChecker,
     MemoryBudget,
     OffloadExecutor,
     PlanRouter,
@@ -30,7 +31,8 @@ from repro.runtime import (
     choose_tile,
     tile_sizes,
 )
-from repro.runtime.tiling import _INTERMEDIATE_FACTOR, BYTES_F32
+from repro.runtime.tiling import (_INTERMEDIATE_FACTOR, BYTES_F32,
+                                  HBM_STAGING_SHARE)
 
 LANED_4F = dataclasses.replace(
     PROTOTYPE_4F, name="laned-4f", interface_latency_s=1.0e-3,
@@ -79,13 +81,50 @@ def test_memory_budget_arithmetic():
     assert u.tile_for(10**9) is None
 
 
+class _Device:
+    """A stand-in for a jax device: ``memory_stats()`` returns ``stats``."""
+
+    def __init__(self, stats):
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+# ``memory_stats()["bytes_limit"]`` of one TPU v5e chip
+V5E_BYTES_LIMIT = 16_909_336_064
+V5E = _Device({"bytes_limit": V5E_BYTES_LIMIT, "bytes_in_use": 0})
+
+
 def test_memory_budget_detect_off_tpu_is_llc_derived():
     b = MemoryBudget.detect(platform="cpu")
     assert b.source == "llc" and b.bytes_limit > 0
-    t = MemoryBudget.detect(platform="tpu")
+    t = MemoryBudget.detect(platform="tpu", device=V5E)
+    assert t.source == "hbm" and t.bytes_limit == V5E_BYTES_LIMIT
+    assert t.spendable_bytes == int(V5E_BYTES_LIMIT * HBM_STAGING_SHARE)
+    # the default platform resolves without error
+    assert MemoryBudget.detect().source in ("llc", "hbm", "vmem")
+
+
+@pytest.mark.parametrize("device", [object(), _Device(None),
+                                    _Device({"bytes_in_use": 0})],
+                         ids=["no-memory-stats", "none", "no-bytes-limit"])
+def test_memory_budget_detect_on_tpu_falls_back_to_vmem(device):
+    """A TPU device that reports no HBM limit keeps the VMEM budget."""
+    t = MemoryBudget.detect(platform="tpu", device=device)
+    assert t == MemoryBudget.vmem()
     assert t.source == "vmem" and t.bytes_limit == 16 * 1024 * 1024
-    # the default platform resolves without error and is one of the two
-    assert MemoryBudget.detect().source in ("llc", "vmem")
+
+
+@pytest.mark.parametrize("device,tile", [(V5E, 8), (_Device(None), 1)],
+                         ids=["hbm", "vmem-fallback"])
+def test_detected_tpu_budget_stages_a_full_aperture_burst_whole(device,
+                                                                tile):
+    """An 8-frame 1024x768 group dispatches once under the HBM budget, and
+    frame by frame under the VMEM one (25.2 MB a frame at depth 2)."""
+    budget = MemoryBudget.detect(platform="tpu", device=device)
+    assert choose_tile(1024 * 768, 8, budget).tile_k == tile
+    assert budget.tile_for_group(1024 * 768, None, 8) == tile
 
 
 # --- choose_tile / tile_sizes -------------------------------------------------
@@ -337,7 +376,7 @@ def test_small_frames_never_tile_under_the_detected_budget():
     untouched: one group, one invocation (the pre-tiling behavior every
     older test asserts on)."""
     ex = OffloadExecutor(SPEC, max_batch=16)
-    assert ex.mem_budget.source in ("llc", "vmem")
+    assert ex.mem_budget.source in ("llc", "hbm", "vmem")
     for h in [ex.submit("fft", im) for im in _imgs(16, (32, 32))]:
         pass
     ex.flush()
@@ -389,6 +428,49 @@ def test_block_plan_cache_keys_by_stack_and_budget():
     tight = ex.ctx.blocks_for(16, 512, 512)           # budget change: fresh
     assert len(ex.ctx.block_cache) == 3
     assert tight.bm < 128 and (tight.bk, tight.bn) == (128, 128)
+
+
+def test_blocks_follow_vmem_under_the_hbm_staging_budget():
+    """The HBM staging budget never sizes a Pallas grid step: the blocks
+    come from VMEM, are keyed by it, and equal the VMEM budget's own."""
+    ex = OffloadExecutor(SPEC, mem_budget=MemoryBudget.detect(
+        platform="tpu", device=V5E))
+    vmem = MemoryBudget.vmem()
+    assert ex.ctx.block_budget == vmem
+    assert ex.ctx.blocks_for(1, 1024, 768).key == (1, 128, 128, 128)
+    assert ex.ctx.blocks_for(8, 1024, 768).key == (8, 128, 128, 128)
+    for batch in (1, 8):
+        assert ex.ctx.blocks_for(batch, 1024, 768) == choose_blocks(
+            batch, 1024, 768, 768, vmem)
+    assert {k[3] for k in ex.ctx.block_cache} == {(vmem.bytes_limit,
+                                                    vmem.reserve)}
+    # every other staging budget sizes the blocks itself, as before
+    for budget in (vmem, _budget_for_frames(64 * 64, 2),
+                   MemoryBudget.unlimited()):
+        assert OffloadExecutor(SPEC, mem_budget=budget).ctx.block_budget \
+            is budget
+
+
+def test_full_aperture_burst_dispatches_once_under_the_hbm_budget():
+    """Eight 1024x768 fft frames flushed under the budget the HBM rule
+    gives on a v5e: one invocation, the answers of one-frame tiles, and
+    one fidelity report that scores all eight frames."""
+    hbm = MemoryBudget.detect(platform="tpu", device=V5E)
+    imgs = _imgs(8, (1024, 768))
+    ex = OffloadExecutor(SPEC, mem_budget=hbm, fidelity=FidelityChecker())
+    hs = [ex.submit("fft", im) for im in imgs]
+    ex.flush()
+    st = ex.telemetry.stats[("fft", "optical-sim")]
+    assert st.calls == 8 and st.invocations == 1
+    (report,) = ex.fidelity.reports
+    assert report.batch == 8 and report.ok
+    assert all(h.fidelity is report for h in hs)
+    looped = OffloadExecutor(SPEC, mem_budget=hbm, tile_k=1)
+    ls = [looped.submit("fft", im) for im in imgs]
+    looped.flush()
+    assert looped.telemetry.stats[("fft", "optical-sim")].invocations == 8
+    for h, lo in zip(hs, ls):
+        np.testing.assert_allclose(h.value, lo.value, rtol=1e-5, atol=1e-5)
 
 
 # --- telemetry ----------------------------------------------------------------

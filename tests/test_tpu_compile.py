@@ -15,7 +15,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.accelerator import PROTOTYPE_4F
 from repro.kernels import adc_dac, local_attention, optical_dft
+from repro.runtime.backends import BackendContext
 from repro.runtime.tiling import MemoryBudget, choose_blocks
 
 HBM_BYTES = 16 * 10**9   # one v5e chip
@@ -66,8 +68,23 @@ def _compile_kernel(fn, *args, **static):
     return compiled
 
 
+class _V5E:
+    """A device that reports one v5e chip's HBM, as ``memory_stats`` does."""
+
+    @staticmethod
+    def memory_stats():
+        return {"bytes_limit": HBM_BYTES}
+
+
+def _detected_block_budget():
+    """The budget ``blocks_for`` sizes the grid step against under the
+    staging budget a v5e's executor detects."""
+    staging = MemoryBudget.detect("tpu", device=_V5E)
+    return BackendContext(spec=PROTOTYPE_4F, mem_budget=staging).block_budget
+
+
 BUDGETS = {
-    "detected": lambda: MemoryBudget.detect("tpu"),
+    "detected": _detected_block_budget,
     # under one 128-cube grid step: the lane blocks must still stay 128
     "manual-200KB": lambda: MemoryBudget(200_000, source="manual"),
 }
