@@ -29,6 +29,7 @@ ARCHS: dict[str, str] = {
     "llava-next-34b": "llava_next_34b",
     "qwen2-moe-a2.7b": "qwen2_moe_a2_7b",
     "deepseek-v3-671b": "deepseek_v3_671b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     "xlstm-125m": "xlstm_125m",
 }
 

@@ -14,7 +14,7 @@ CONFIG = ModelConfig(
     mla=MLAConfig(q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
                   qk_rope_head_dim=64, v_head_dim=128),
     moe=MoEConfig(n_routed=256, top_k=8, d_expert=2048, n_shared=1,
-                  d_shared=2048, shard_mode="ep"),
+                  d_shared=2048, shard_mode="ep", scoring="sigmoid"),
     param_dtype="bfloat16", logit_chunks=16,
 )
 
@@ -24,6 +24,6 @@ SMOKE = dataclasses.replace(
     logit_chunks=2,
     mla=MLAConfig(q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=8,
                   qk_rope_head_dim=4, v_head_dim=8),
-    moe=MoEConfig(n_routed=8, top_k=2, d_expert=32, n_shared=1,
-                  d_shared=32, shard_mode="ep"),
+    moe=dataclasses.replace(CONFIG.moe, n_routed=8, top_k=2, d_expert=32,
+                            d_shared=32),
 )

@@ -12,7 +12,7 @@ CONFIG = ModelConfig(
     n_layers=24, d_model=2048, n_heads=16, n_kv_heads=16, d_ff=5632,
     vocab_size=151936, attn_bias=True, rope_theta=1e6,
     moe=MoEConfig(n_routed=60, top_k=4, d_expert=1408, n_shared=4,
-                  d_shared=1408, shard_mode="tp"),
+                  d_shared=1408, shard_mode="tp", scoring="softmax"),
     param_dtype="bfloat16", logit_chunks=8,
 )
 
@@ -20,6 +20,6 @@ SMOKE = dataclasses.replace(
     CONFIG, n_layers=2, d_model=64, n_heads=4, n_kv_heads=4, d_ff=128,
     vocab_size=500, vocab_pad_multiple=64, param_dtype="float32",
     logit_chunks=2,
-    moe=MoEConfig(n_routed=8, top_k=2, d_expert=32, n_shared=2,
-                  d_shared=32, shard_mode="tp"),
+    moe=dataclasses.replace(CONFIG.moe, n_routed=8, top_k=2, d_expert=32,
+                            n_shared=2, d_shared=32),
 )

@@ -75,7 +75,7 @@ def cache_pspecs(cfg: ModelConfig, cache_like: Any,
                 spec = (dp, None, None, None)
         elif name == "latent" and len(shape) == 3:
             spec = (dp, None, _shard_last(shape[-1], tp))
-        elif name == "slot_pos" or name in ("pos",):
+        elif name in ("slot_pos", "pos", "expert_tokens"):
             spec = tuple([None] * len(shape))
         elif name == "enc_out":
             spec = (dp,) + (None,) * (len(shape) - 1)

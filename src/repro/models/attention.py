@@ -178,8 +178,12 @@ def gqa_decode_cross(cfg: ModelConfig, p: dict, x: jax.Array,
 def _mla_q(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array):
     m = cfg.mla
     h = cfg.n_heads
-    cq = rms_norm(x @ p["w_dq"].astype(x.dtype), p["q_norm"], cfg.norm_eps)
-    q = (cq @ p["w_uq"].astype(x.dtype)).reshape(*x.shape[:-1], h, m.qk_head_dim)
+    if m.q_lora_rank is None:
+        q = x @ p["w_q"].astype(x.dtype)
+    else:
+        cq = rms_norm(x @ p["w_dq"].astype(x.dtype), p["q_norm"], cfg.norm_eps)
+        q = cq @ p["w_uq"].astype(x.dtype)
+    q = q.reshape(*x.shape[:-1], h, m.qk_head_dim)
     q_nope, q_rope = q[..., :m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
     q_rope = rope(q_rope, positions, theta=cfg.rope_theta)
     return q_nope, q_rope

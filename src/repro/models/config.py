@@ -29,25 +29,41 @@ class MoEConfig:
     d_expert: int                 # intermediate size of each routed expert
     n_shared: int = 0
     d_shared: int | None = None   # intermediate size of each shared expert
-    router_noise: float = 0.0
     aux_loss_coef: float = 0.001
     # "ep"  -> experts sharded over the model axis (one expert group/chip)
     # "tp"  -> every expert's ffn dim sharded over the model axis
     shard_mode: str = "ep"
-    # tokens per expert = ceil(S * top_k * capacity_factor / n_routed);
-    # overflow tokens fall through the residual (standard dropped-token MoE)
-    capacity_factor: float = 1.25
+    # router: scores s = softmax|sigmoid(x W_r) over all ``n_routed``; the
+    # top_k of s (of s + b, b the learned ``router_bias``, with
+    # ``selection_bias``) are chosen and weighted by s, normalised over the
+    # k, times ``routed_scale``
+    scoring: str = "softmax"
+    selection_bias: bool = False
+    routed_scale: float = 1.0
+    # experts held here: ``n_held`` of them from ``first_held`` (None: all
+    # ``n_routed``); tokens routed elsewhere add nothing on this chip
+    first_held: int = 0
+    n_held: int | None = None
+    # None -> dropless.  Else each expert takes at most
+    # ceil(S * top_k * capacity_factor / n_routed) tokens of a batch row;
+    # overflow tokens fall through the residual (dropped-token MoE)
+    capacity_factor: float | None = 1.25
 
     @property
     def d_shared_total(self) -> int:
         return (self.d_shared or self.d_expert) * self.n_shared
 
+    @property
+    def held(self) -> int:
+        return self.n_routed if self.n_held is None else self.n_held
+
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-V3 multi-head latent attention dims."""
+    """DeepSeek-V3 multi-head latent attention dims; ``q_lora_rank`` None
+    projects the query directly (Moonlight)."""
 
-    q_lora_rank: int = 1536
+    q_lora_rank: int | None = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -103,6 +119,15 @@ class ModelConfig:
     logit_chunks: int = 8         # chunked CE to bound the logits peak
     vocab_pad_multiple: int = 2048  # pad tables so "model"-axis sharding divides
 
+    def __post_init__(self) -> None:
+        # a configuration read from JSON brings its blocks as dicts and
+        # its pattern as a list
+        if isinstance(self.moe, dict):
+            object.__setattr__(self, "moe", MoEConfig(**self.moe))
+        if isinstance(self.mla, dict):
+            object.__setattr__(self, "mla", MLAConfig(**self.mla))
+        object.__setattr__(self, "pattern", tuple(self.pattern))
+
     # ----- derived -------------------------------------------------------------
     @property
     def head_dim_(self) -> int:
@@ -132,8 +157,13 @@ class ModelConfig:
     def layer_plan(self) -> "LayerPlan":
         return LayerPlan.build(self.layer_kinds(), self.pattern, self.dense_prefix)
 
-    def uses_moe_at(self, layer_idx: int) -> bool:
-        return self.moe is not None and layer_idx >= self.dense_prefix
+    @property
+    def n_moe_layers(self) -> int:
+        """Layers whose MLP is the expert layer: the attention blocks past
+        the dense prefix."""
+        if self.moe is None:
+            return 0
+        return self.layer_kinds()[self.dense_prefix:].count("attn")
 
     # Parameter counts are computed from the actual template tree — see
     # ``repro.models.params.param_counts`` — so they can never drift from

@@ -39,7 +39,7 @@ __all__ = ["LM"]
 def _block_full(cfg: ModelConfig, kind: str, p: dict, x: jax.Array, *,
                 pos0: int, dense: bool, enc_out: jax.Array | None,
                 causal: bool, build_cache: bool):
-    """Returns (x, aux_loss, cache_or_None)."""
+    """Returns (x, aux_loss, cache_or_None, expert_load_or_None)."""
     aux = jnp.zeros((), jnp.float32)
     cache = None
     x = constrain_residual(x)
@@ -69,10 +69,10 @@ def _block_full(cfg: ModelConfig, kind: str, p: dict, x: jax.Array, *,
                                   causal=False, use_rope=False)
         h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
         if cfg.moe is not None and not dense:
-            y2, aux = moe_apply(cfg, p["mlp"], h2)
+            y2, aux, load = moe_apply(cfg, p["mlp"], h2)
         else:
-            y2 = mlp_apply(cfg, p["mlp"], h2)
-        return x + y2, aux, cache
+            y2, load = mlp_apply(cfg, p["mlp"], h2), None
+        return x + y2, aux, cache, load
 
     if kind == "rglru":
         h_in = rms_norm(x, p["ln1"], cfg.norm_eps)
@@ -83,7 +83,7 @@ def _block_full(cfg: ModelConfig, kind: str, p: dict, x: jax.Array, *,
             y = rec.rglru_full(cfg, p["rglru"], h_in)
         x = x + y
         h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        return x + mlp_apply(cfg, p["mlp"], h2), aux, cache
+        return x + mlp_apply(cfg, p["mlp"], h2), aux, cache, None
 
     if kind == "mlstm":
         h_in = rms_norm(x, p["ln1"], cfg.norm_eps)
@@ -92,7 +92,7 @@ def _block_full(cfg: ModelConfig, kind: str, p: dict, x: jax.Array, *,
             cache = st
         else:
             y = rec.mlstm_full(cfg, p["mlstm"], h_in)
-        return x + y, aux, cache
+        return x + y, aux, cache, None
 
     if kind == "slstm":
         h_in = rms_norm(x, p["ln1"], cfg.norm_eps)
@@ -103,7 +103,7 @@ def _block_full(cfg: ModelConfig, kind: str, p: dict, x: jax.Array, *,
             y = rec.slstm_full(cfg, p["slstm"], h_in)
         x = x + y
         h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        return x + rec.slstm_ffn(p["slstm"], h2), aux, cache
+        return x + rec.slstm_ffn(p["slstm"], h2), aux, cache, None
 
     raise ValueError(f"unknown block kind {kind!r}")
 
@@ -111,7 +111,7 @@ def _block_full(cfg: ModelConfig, kind: str, p: dict, x: jax.Array, *,
 def _block_decode(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
                   cache: dict, pos: jax.Array, *, dense: bool,
                   enc_out: jax.Array | None):
-    """One-token step.  Returns (x, new_cache)."""
+    """One-token step.  Returns (x, new_cache, expert_load_or_None)."""
     if kind == "attn":
         h_in = rms_norm(x, p["ln1"], cfg.norm_eps)
         if cfg.mla is not None:
@@ -125,27 +125,27 @@ def _block_decode(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
             x = x + attn.gqa_decode_cross(cfg, p["xattn"], xh, enc_out)
         h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
         if cfg.moe is not None and not dense:
-            y2, _ = moe_apply(cfg, p["mlp"], h2)
+            y2, _, load = moe_apply(cfg, p["mlp"], h2)
         else:
-            y2 = mlp_apply(cfg, p["mlp"], h2)
-        return x + y2, cache
+            y2, load = mlp_apply(cfg, p["mlp"], h2), None
+        return x + y2, cache, load
 
     if kind == "rglru":
         h_in = rms_norm(x, p["ln1"], cfg.norm_eps)
         y, cache = rec.rglru_decode(cfg, p["rglru"], h_in, cache)
         x = x + y
-        return x + mlp_apply(cfg, p["mlp"], rms_norm(x, p["ln2"], cfg.norm_eps)), cache
+        return x + mlp_apply(cfg, p["mlp"], rms_norm(x, p["ln2"], cfg.norm_eps)), cache, None
 
     if kind == "mlstm":
         h_in = rms_norm(x, p["ln1"], cfg.norm_eps)
         y, cache = rec.mlstm_decode(cfg, p["mlstm"], h_in, cache)
-        return x + y, cache
+        return x + y, cache, None
 
     if kind == "slstm":
         h_in = rms_norm(x, p["ln1"], cfg.norm_eps)
         y, cache = rec.slstm_decode(cfg, p["slstm"], h_in, cache)
         x = x + y
-        return x + rec.slstm_ffn(p["slstm"], rms_norm(x, p["ln2"], cfg.norm_eps)), cache
+        return x + rec.slstm_ffn(p["slstm"], rms_norm(x, p["ln2"], cfg.norm_eps)), cache, None
 
     raise ValueError(kind)
 
@@ -196,6 +196,13 @@ def _cache_from_prefill(cfg: ModelConfig, kind: str, built: dict | None,
     return built  # recurrent states carry over unchanged
 
 
+def _stack_loads(loads: list) -> jax.Array | None:
+    """Per-held-expert pair counts of the expert layers, in layer order:
+    (n_moe_layers, held) int32, or None for a model without experts."""
+    loads = [l.reshape(-1, l.shape[-1]) for l in loads if l is not None]
+    return jnp.concatenate(loads) if loads else None
+
+
 # --------------------------------------------------------------------------- #
 # whole model                                                                  #
 # --------------------------------------------------------------------------- #
@@ -231,9 +238,9 @@ class LM:
         enc = params["encoder"]
 
         def body(x, lp):
-            x, _, _ = _block_full(cfg, "attn", lp["0_attn"], x, pos0=0,
-                                  dense=True, enc_out=None, causal=False,
-                                  build_cache=False)
+            x, _, _, _ = _block_full(cfg, "attn", lp["0_attn"], x, pos0=0,
+                                     dense=True, enc_out=None, causal=False,
+                                     build_cache=False)
             return x, None
 
         x, _ = jax.lax.scan(jax.checkpoint(body), frames, enc["stack"])
@@ -245,23 +252,26 @@ class LM:
         cfg = self.cfg
         aux = jnp.zeros((), jnp.float32)
         caches = {}
+        loads = []
         for key in sorted(sp.keys(), key=lambda s: int(s.split("_")[0])):
             kind = key.split("_", 1)[1]
-            x, a, c = _block_full(cfg, kind, sp[key], x, pos0=pos0, dense=False,
-                                  enc_out=enc_out, causal=True,
-                                  build_cache=build_cache)
+            x, a, c, l = _block_full(cfg, kind, sp[key], x, pos0=pos0,
+                                     dense=False, enc_out=enc_out, causal=True,
+                                     build_cache=build_cache)
             aux = aux + a
+            loads.append(l)
             if build_cache:
                 caches[key] = c
-        return x, aux, caches
+        return x, aux, caches, _stack_loads(loads)
 
     def _forward(self, params: dict, x: jax.Array, *, enc_out=None,
                  build_cache: bool = False, remat: bool = True):
-        """Shared full-sequence traversal.  Returns (x, aux, caches)."""
+        """Shared full-sequence traversal.  Returns (x, aux, caches, expert
+        loads); the caches and loads only with ``build_cache``."""
         cfg = self.cfg
-        plan = cfg.layer_plan()
         aux_total = jnp.zeros((), jnp.float32)
         caches: dict[str, Any] = {}
+        loads: list = []
 
         for section, dense in (("prefix", True), ):
             if section in params:
@@ -269,19 +279,20 @@ class LM:
                 for key in sorted(params[section],
                                   key=lambda s: int(s.split("_")[0])):
                     kind = key.split("_", 1)[1]
-                    x, a, c = _block_full(cfg, kind, params[section][key], x,
-                                          pos0=0, dense=dense, enc_out=enc_out,
-                                          causal=True, build_cache=build_cache)
+                    x, a, c, l = _block_full(cfg, kind, params[section][key], x,
+                                             pos0=0, dense=dense, enc_out=enc_out,
+                                             causal=True, build_cache=build_cache)
                     aux_total = aux_total + a
                     if build_cache:
                         caches[section][key] = c
+                        loads.append(l)
 
         if "stack" in params:
             def body(carry, lp):
                 xx, aux = carry
-                xx, a, c = self._super_full(lp, xx, pos0=0, enc_out=enc_out,
-                                            build_cache=build_cache)
-                return (xx, aux + a), c
+                xx, a, c, l = self._super_full(lp, xx, pos0=0, enc_out=enc_out,
+                                               build_cache=build_cache)
+                return (xx, aux + a), ((c, l) if build_cache else None)
 
             # remat policy (REPRO_REMAT_POLICY): 'full' recomputes everything
             # in backward (min residency, max recompute); 'dots' saves matmul
@@ -311,32 +322,34 @@ class LM:
 
                 (x, aux_total), _ = jax.lax.scan(
                     jax.checkpoint(group_body), (x, aux_total), grouped)
-                stack_caches = None
+                stack_out = None
             else:
-                (x, aux_total), stack_caches = jax.lax.scan(
+                (x, aux_total), stack_out = jax.lax.scan(
                     body_fn, (x, aux_total), params["stack"])
             if build_cache:
-                caches["stack"] = stack_caches
+                caches["stack"], stack_loads = stack_out
+                loads.append(stack_loads)
 
         if "tail" in params:
             caches["tail"] = {}
             for key in sorted(params["tail"], key=lambda s: int(s.split("_")[0])):
                 kind = key.split("_", 1)[1]
-                x, a, c = _block_full(cfg, kind, params["tail"][key], x,
-                                      pos0=0, dense=False, enc_out=enc_out,
-                                      causal=True, build_cache=build_cache)
+                x, a, c, l = _block_full(cfg, kind, params["tail"][key], x,
+                                         pos0=0, dense=False, enc_out=enc_out,
+                                         causal=True, build_cache=build_cache)
                 aux_total = aux_total + a
                 if build_cache:
                     caches["tail"][key] = c
+                    loads.append(l)
 
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return x, aux_total, caches
+        return x, aux_total, caches, _stack_loads(loads)
 
     # ----- public entry points ---------------------------------------------------
     def loss(self, params: dict, batch: dict, *, remat: bool = True):
         cfg = self.cfg
         x, labels, enc_out = self._inputs(params, batch)
-        x, aux, _ = self._forward(params, x, enc_out=enc_out, remat=remat)
+        x, aux, _, _ = self._forward(params, x, enc_out=enc_out, remat=remat)
         head = params["embed"] if cfg.tie_embeddings else params["head"]
         ce, metrics = chunked_ce_loss(cfg, head, x, labels)
         metrics["aux_loss"] = aux
@@ -344,14 +357,18 @@ class LM:
 
     def prefill(self, params: dict, batch: dict, *, max_len: int,
                 remat: bool = True):
-        """Forward + cache build.  Returns (cache, last-position logits)."""
+        """Forward + cache build.  Returns (cache, last-position logits).
+        With experts, ``cache["expert_tokens"]`` holds the prefill's
+        (token, expert) pairs per expert layer and held expert."""
         cfg = self.cfg
         x, _, enc_out = self._inputs(params, batch)
         b, s, _ = x.shape
-        x, _, built = self._forward(params, x, enc_out=enc_out,
-                                    build_cache=True, remat=remat)
+        x, _, built, loads = self._forward(params, x, enc_out=enc_out,
+                                           build_cache=True, remat=remat)
         cache = self._caches_to_decode(built, b, s, max_len)
         cache["pos"] = jnp.full((b,), s, jnp.int32)  # per-lane positions
+        if loads is not None:
+            cache["expert_tokens"] = loads
         if enc_out is not None:
             cache["enc_out"] = enc_out
         head = params["embed"] if cfg.tie_embeddings else params["head"]
@@ -381,6 +398,9 @@ class LM:
         cfg = self.cfg
         plan = cfg.layer_plan()
         out: dict[str, Any] = {"pos": jnp.zeros((batch,), jnp.int32)}
+        if cfg.moe is not None:
+            out["expert_tokens"] = jnp.zeros((cfg.n_moe_layers, cfg.moe.held),
+                                             jnp.int32)
         if plan.prefix:
             out["prefix"] = {f"{i}_{k}": _init_block_cache(cfg, k, batch, max_len)
                              for i, k in enumerate(plan.prefix)}
@@ -397,12 +417,15 @@ class LM:
         return out
 
     def decode_step(self, params: dict, cache: dict, tokens: jax.Array):
-        """tokens: (B, 1) int32.  Returns (logits (B, V), new cache)."""
+        """tokens: (B, 1) int32.  Returns (logits (B, V), new cache); with
+        experts, the new cache's ``expert_tokens`` holds this step's
+        (token, expert) pairs per expert layer and held expert."""
         cfg = self.cfg
         pos = cache["pos"]
         enc_out = cache.get("enc_out")
         x = embed_tokens(cfg, params["embed"], tokens)
         new_cache: dict[str, Any] = {"pos": pos + 1}
+        loads: list = []
         if enc_out is not None:
             new_cache["enc_out"] = enc_out
 
@@ -412,34 +435,41 @@ class LM:
                 for key in sorted(params[section],
                                   key=lambda s: int(s.split("_")[0])):
                     kind = key.split("_", 1)[1]
-                    x, c = _block_decode(cfg, kind, params[section][key], x,
-                                         cache[section][key], pos, dense=True,
-                                         enc_out=enc_out)
+                    x, c, l = _block_decode(cfg, kind, params[section][key], x,
+                                            cache[section][key], pos, dense=True,
+                                            enc_out=enc_out)
                     new_cache[section][key] = c
+                    loads.append(l)
 
         if "stack" in params:
             def body(xx, inp):
                 lp, lc = inp
-                ncs = {}
+                ncs, ls = {}, []
                 for key in sorted(lp.keys(), key=lambda s: int(s.split("_")[0])):
                     kind = key.split("_", 1)[1]
-                    xx, nc = _block_decode(cfg, kind, lp[key], xx, lc[key], pos,
-                                           dense=False, enc_out=enc_out)
+                    xx, nc, l = _block_decode(cfg, kind, lp[key], xx, lc[key],
+                                              pos, dense=False, enc_out=enc_out)
                     ncs[key] = nc
-                return xx, ncs
+                    ls.append(l)
+                return xx, (ncs, _stack_loads(ls))
 
-            x, stack_cache = jax.lax.scan(body, x,
-                                          (params["stack"], cache["stack"]))
+            x, (stack_cache, stack_loads) = jax.lax.scan(
+                body, x, (params["stack"], cache["stack"]))
             new_cache["stack"] = stack_cache
+            loads.append(stack_loads)
 
         if "tail" in params:
             new_cache["tail"] = {}
             for key in sorted(params["tail"], key=lambda s: int(s.split("_")[0])):
                 kind = key.split("_", 1)[1]
-                x, c = _block_decode(cfg, kind, params["tail"][key], x,
-                                     cache["tail"][key], pos, dense=False,
-                                     enc_out=enc_out)
+                x, c, l = _block_decode(cfg, kind, params["tail"][key], x,
+                                        cache["tail"][key], pos, dense=False,
+                                        enc_out=enc_out)
                 new_cache["tail"][key] = c
+                loads.append(l)
+        loads = _stack_loads(loads)
+        if loads is not None:
+            new_cache["expert_tokens"] = loads
 
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = params["embed"] if cfg.tie_embeddings else params["head"]

@@ -1,47 +1,74 @@
-"""Mixture-of-Experts layer: shared + routed top-k, sort-based dispatch.
+"""Mixture-of-Experts layer: shared experts + routed top-k over the experts
+held here, dropless by default.
 
-Dispatch strategy (chosen for SPMD friendliness at 256 experts / 512 chips):
-tokens are routed *per batch row* — assignments are sorted along the
-unsharded (S*k) axis, positions-within-expert computed from segment starts,
-and tokens beyond each expert's capacity C = ceil(S*k*cf / E) are dropped
-(standard capacity-factor semantics).  The gathered (B, E, C, D) activation
-is then sharding-constrained to (data, model, ..., ...) so XLA lowers the
-expert exchange as an all-to-all on the ``model`` axis — expert parallelism
-— rather than an all-gather of the full token set.
+Router (``MoEConfig`` fields, computed in float32): scores
+``s = softmax|sigmoid(x W_r)`` over all ``n_routed`` experts; the top-k of
+``s`` (of ``s + b`` with ``selection_bias``, DeepSeek's ``noaux_tc``) are
+chosen, weighted by ``s`` normalised over the k, times ``routed_scale``.
 
-``shard_mode='ep'``  : expert dim over ``model``  (DeepSeek-V3: 256 experts).
-``shard_mode='tp'``  : each expert's ffn dim over ``model``
-                       (Qwen2-MoE: 60 experts don't divide 16 chips).
+Dispatch: a chip holds experts ``first_held .. first_held + held - 1``.
+The batch rows split into dispatch groups, one per batch shard of the
+context mesh (one off-mesh), so the sort stays along unsharded axes.
+Within a group every (token, expert) pair is sorted by expert, pairs of
+absent experts last, and the held experts' MLPs run as grouped matmuls
+(``jax.lax.ragged_dot``) over the sorted rows: every pair routed to a held
+expert is computed, at static shapes (top_k rows a token), and the absent
+experts add nothing.  With a ``capacity_factor`` the pairs past each
+expert's capacity in a batch row are marked absent first (the
+dropped-token MoE the training configurations use).
+
+The layer returns its per-held-expert pair counts beside its output.
 """
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import constrain
 from repro.models.config import ModelConfig
 
-__all__ = ["moe_apply"]
+__all__ = ["moe_apply", "route"]
 
 
-def _router(cfg: ModelConfig, p: dict, x2d: jax.Array):
-    """x2d: (B, S, D) -> (probs (B,S,k), idx (B,S,k), aux_loss)."""
+def route(cfg: ModelConfig, p: dict, x: jax.Array):
+    """x: (..., D) -> (weights (..., k) f32, experts (..., k), aux_loss)."""
     m = cfg.moe
-    logits = (x2d @ p["router"].astype(x2d.dtype)).astype(jnp.float32)
-    if cfg.name.startswith("deepseek"):
-        scores = jax.nn.sigmoid(logits)             # DeepSeek-V3 sigmoid router
+    logits = jnp.matmul(x.astype(jnp.float32), p["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    if m.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
     else:
         scores = jax.nn.softmax(logits, axis=-1)
-    top, idx = jax.lax.top_k(scores, m.top_k)
-    top = top / jnp.maximum(top.sum(-1, keepdims=True), 1e-9)
+    choice = scores + p["router_bias"].astype(jnp.float32) \
+        if m.selection_bias else scores
+    _, idx = jax.lax.top_k(choice, m.top_k)
+    w = jnp.take_along_axis(scores, idx, -1)
+    w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9) * m.routed_scale
     # Switch-style load-balance auxiliary loss.
     e = m.n_routed
-    assign = jax.nn.one_hot(idx, e, dtype=jnp.float32).sum(-2)   # (B,S,E)
-    frac = assign.mean(axis=(0, 1)) / m.top_k
-    prob = jax.nn.softmax(logits, axis=-1).mean(axis=(0, 1))
+    assign = jax.nn.one_hot(idx, e, dtype=jnp.float32).sum(-2)
+    frac = assign.reshape(-1, e).mean(0) / m.top_k
+    prob = jax.nn.softmax(logits, axis=-1).reshape(-1, e).mean(0)
     aux = m.aux_loss_coef * e * jnp.sum(frac * prob)
-    return top, idx, aux
+    return w, idx, aux
+
+
+def _within_capacity(cfg: ModelConfig, idx: jax.Array) -> jax.Array:
+    """idx: (B, S, k) -> (B, S*k) bool: the first ``capacity`` pairs of each
+    expert in each batch row, in (token, slot) order."""
+    m = cfg.moe
+    b, s, k = idx.shape
+    e = m.n_routed
+    cap = max(-(-s * k * int(4 * m.capacity_factor) // (4 * e)), 1)
+    flat = idx.reshape(b, s * k)
+    order = jnp.argsort(flat, axis=-1, stable=True)
+    se = jnp.take_along_axis(flat, order, -1)
+    starts = jax.vmap(lambda row: jnp.searchsorted(row, jnp.arange(e)))(se)
+    rank = jnp.arange(s * k)[None, :] - jnp.take_along_axis(starts, se, -1)
+    rows = jnp.arange(b)[:, None]
+    return jnp.zeros((b, s * k), bool).at[rows, order].set(rank < cap)
 
 
 def _shared(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
@@ -51,55 +78,62 @@ def _shared(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
     return h @ p["ws_out"].astype(x.dtype)
 
 
+def _dispatch_groups(b: int) -> int:
+    """Batch shards of the context mesh (its ``pod`` x ``data`` extent)
+    when they divide the ``b`` rows, else 1."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return 1
+    g = math.prod(mesh.shape[a] for a in ("pod", "data")
+                  if a in mesh.axis_names)
+    return g if b % g == 0 else 1
+
+
+def _experts(xs: jax.Array, sizes: jax.Array, w_in: jax.Array,
+             w_gate: jax.Array, w_out: jax.Array) -> jax.Array:
+    """Held experts' MLPs over rows sorted by expert, ``sizes`` rows each."""
+    h = jax.nn.silu(jax.lax.ragged_dot(xs, w_in, sizes)) \
+        * jax.lax.ragged_dot(xs, w_gate, sizes)
+    return jax.lax.ragged_dot(h, w_out, sizes)
+
+
 def moe_apply(cfg: ModelConfig, p: dict, x: jax.Array):
-    """x: (B, S, D) -> (out (B, S, D), aux_loss scalar)."""
+    """x: (B, S, D) -> (out (B, S, D), aux_loss, pairs per held expert
+    (held,) int32)."""
     m = cfg.moe
     b, s, d = x.shape
-    e, k = m.n_routed, m.top_k
-    cap = max(-(-s * k * int(4 * m.capacity_factor) // (4 * e)), 1)
+    k, held = m.top_k, m.held
+    g = _dispatch_groups(b)
+    n = b * s * k // g                             # pairs of a group
+    w, idx, aux = route(cfg, p, x)
 
-    top, idx, aux = _router(cfg, p, x)
+    local = (idx - m.first_held).reshape(g, n)
+    here = (local >= 0) & (local < held)
+    if m.capacity_factor is not None:
+        here &= _within_capacity(cfg, idx).reshape(g, n)
+    key = jnp.where(here, local, held)             # absent pairs sort last
+    order = jnp.argsort(key, axis=-1, stable=True)
+    sizes = jax.vmap(lambda r: jnp.bincount(r, length=held + 1))(key)
+    sizes = sizes[:, :held].astype(jnp.int32)
 
-    # ---- build per-row dispatch (all along unsharded axes) ----------------
-    flat_e = idx.reshape(b, s * k)                        # expert of each slot
-    flat_t = jnp.repeat(jnp.arange(s), k)[None, :]        # token of each slot
-    flat_t = jnp.broadcast_to(flat_t, (b, s * k))
-    flat_p = top.reshape(b, s * k)
+    xs = jnp.take_along_axis(x.reshape(g, n // k, d),
+                             (order // k)[..., None], 1)   # by expert
+    ws = [p[name].astype(x.dtype) for name in ("we_in", "we_gate", "we_out")]
+    if g == 1:
+        y = _experts(xs[0], sizes[0], *ws)[None]
+    else:   # ragged_dot batches only with its weights batched too
+        y = jax.vmap(_experts)(xs, sizes,
+                               *[jnp.broadcast_to(w_, (g,) + w_.shape)
+                                 for w_ in ws])
+    # rows past the held groups belong to no expert: zero, whatever the
+    # grouped matmul leaves there
+    y = jnp.where((jnp.take_along_axis(key, order, -1) < held)[..., None],
+                  y, 0)
 
-    order = jnp.argsort(flat_e, axis=-1, stable=True)
-    se = jnp.take_along_axis(flat_e, order, -1)
-    st = jnp.take_along_axis(flat_t, order, -1)
-    sp = jnp.take_along_axis(flat_p, order, -1)
-    # position within expert segment
-    starts = jax.vmap(lambda row: jnp.searchsorted(row, jnp.arange(e)))(se)
-    pos_in_e = jnp.arange(s * k)[None, :] - jnp.take_along_axis(starts, se, -1)
-    keep = pos_in_e < cap
-    dest = jnp.where(keep, se * cap + pos_in_e, e * cap)  # overflow -> slot E*C
-
-    # token index per (expert, capacity) slot; S = padding token
-    slot_tok = jnp.full((b, e * cap + 1), s, jnp.int32)
-    slot_w = jnp.zeros((b, e * cap + 1), jnp.float32)
-    rows = jnp.arange(b)[:, None]
-    slot_tok = slot_tok.at[rows, dest].set(jnp.where(keep, st, s).astype(jnp.int32))
-    slot_w = slot_w.at[rows, dest].set(jnp.where(keep, sp, 0.0))
-    slot_tok, slot_w = slot_tok[:, :-1], slot_w[:, :-1]
-
-    # ---- gather -> expert compute -> combine ------------------------------
-    xp = jnp.concatenate([x, jnp.zeros((b, 1, d), x.dtype)], axis=1)
-    gx = jnp.take_along_axis(xp, slot_tok[..., None], axis=1)  # (B, E*C, D)
-    gx = gx.reshape(b, e, cap, d)
-    if m.shard_mode == "ep":
-        gx = constrain(gx, ("pod", "data"), "model", None, None)
-
-    w_in = p["we_in"].astype(x.dtype)
-    w_gate = p["we_gate"].astype(x.dtype)
-    w_out = p["we_out"].astype(x.dtype)
-    h = jax.nn.silu(jnp.einsum("becd,edf->becf", gx, w_in))
-    h = h * jnp.einsum("becd,edf->becf", gx, w_gate)
-    eo = jnp.einsum("becf,efd->becd", h, w_out)            # (B,E,C,D)
-
-    eo = eo.reshape(b, e * cap, d) * slot_w[..., None].astype(x.dtype)
-    out = jnp.zeros((b, s + 1, d), x.dtype)
-    out = out.at[rows, slot_tok].add(eo)[:, :s, :]
-
-    return out + _shared(cfg, p, x), aux
+    # back to (token, slot) order; combine in float32
+    inv = jax.vmap(lambda o: jnp.zeros_like(o).at[o].set(jnp.arange(n)))(order)
+    wk = jnp.where(here, w.reshape(g, n), 0.0)
+    y = jnp.take_along_axis(y, inv[..., None], 1).astype(jnp.float32) \
+        * wk[..., None]
+    out = y.reshape(b, s, k, d).sum(axis=2).astype(x.dtype)
+    return out + _shared(cfg, p, x), aux, sizes.sum(0)

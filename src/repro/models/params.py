@@ -57,19 +57,25 @@ def _mlp_templates(cfg: ModelConfig, dense: bool) -> dict[str, ParamSpec]:
     d = cfg.d_model
     if cfg.moe is not None and not dense:
         m = cfg.moe
-        fe, fs = m.d_expert, (m.d_shared or m.d_expert) * max(m.n_shared, 1)
+        e, fe = m.held, m.d_expert
+        fs = (m.d_shared or m.d_expert) * max(m.n_shared, 1)
         if m.shard_mode == "ep":
             ep = lambda *s: ("model",) + (None,) * (len(s) - 1)
         else:  # tp: shard each expert's ffn dim
             ep = lambda *s: (None, None, "model") if len(s) == 3 else (None,)
         t = {
             "router": ParamSpec((d, m.n_routed), (None, None), "normal02"),
-            "we_in": ParamSpec((m.n_routed, d, fe), ep(m.n_routed, d, fe)),
-            "we_gate": ParamSpec((m.n_routed, d, fe), ep(m.n_routed, d, fe)),
+            "we_in": ParamSpec((e, d, fe), ep(e, d, fe)),
+            "we_gate": ParamSpec((e, d, fe), ep(e, d, fe)),
             "we_out": ParamSpec(
-                (m.n_routed, fe, d),
+                (e, fe, d),
                 ("model", None, None) if m.shard_mode == "ep" else (None, "model", None)),
         }
+        if m.selection_bias:
+            # the router's selection bias, kept in float32 as published
+            # (DeepSeek-V3's e_score_correction_bias); drawn small
+            t["router_bias"] = ParamSpec((m.n_routed,), (None,), "normal02",
+                                         "float32")
         if m.n_shared:
             t.update({
                 "ws_in": ParamSpec((d, fs), (None, "model")),
@@ -89,10 +95,15 @@ def _attn_templates(cfg: ModelConfig, cross: bool = False) -> dict[str, ParamSpe
     d, h, hk, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
     if cfg.mla is not None and not cross:
         m = cfg.mla
+        if m.q_lora_rank is None:
+            q = {"w_q": ParamSpec((d, h * m.qk_head_dim), (None, "model"))}
+        else:
+            q = {"w_dq": ParamSpec((d, m.q_lora_rank), (None, None)),
+                 "q_norm": _norm(m.q_lora_rank),
+                 "w_uq": ParamSpec((m.q_lora_rank, h * m.qk_head_dim),
+                                   (None, "model"))}
         return {
-            "w_dq": ParamSpec((d, m.q_lora_rank), (None, None)),
-            "q_norm": _norm(m.q_lora_rank),
-            "w_uq": ParamSpec((m.q_lora_rank, h * m.qk_head_dim), (None, "model")),
+            **q,
             "w_dkv": ParamSpec((d, m.kv_lora_rank + m.qk_rope_head_dim), (None, None)),
             "kv_norm": _norm(m.kv_lora_rank),
             "w_uk": ParamSpec((m.kv_lora_rank, h * m.qk_nope_head_dim), (None, "model")),

@@ -44,8 +44,15 @@ plus a retrospective ``queued`` span per request (``submit`` to the start
 of its admission) and the counters ``decode_steps``, ``admitted``,
 ``prefill_tokens`` (left padding included) and ``decode_positions`` (the
 positions each decode step attended, summed over its live lanes) in
-``tracer.metrics``.  No span adds a host-device sync; without a tracer
-no span site calls into the tracer or the profiler.
+``tracer.metrics``.  A model with experts also annotates each
+``engine.prefill`` and ``engine.decode`` span with the expert layers' load
+and counts it: ``expert_tokens`` (the (token, held expert) pairs
+computed), ``experts_reached`` (held experts of all expert layers that
+got a token) and ``max_expert_tokens`` (the most pairs one held expert
+got).  The load comes back in the fetch the step already makes (the
+first-token argmax at admission, the positions after a decode).  No span
+adds a host-device sync; without a tracer no span site calls into the
+tracer or the profiler.
 """
 
 from __future__ import annotations
@@ -155,6 +162,20 @@ class ServingEngine:
             return dst
         self.cache = jax.tree_util.tree_map(put, self.cache, slot_cache)
 
+    def _load(self, cache: dict):
+        """The expert load a cache carries, where it is to be recorded."""
+        return cache.get("expert_tokens") if self.tracer is not None else None
+
+    def _record_load(self, span: Any, load: Any) -> None:
+        if load is None:
+            return
+        got = {"expert_tokens": int(load.sum()),
+               "experts_reached": int((load > 0).sum()),
+               "max_expert_tokens": int(load.max())}
+        span.annotate(**got)
+        for name, n in got.items():
+            self.tracer.metrics.counter(name).inc(n)
+
     def _admit(self) -> None:
         tr = self.tracer
         free = [s for s in range(self.slots) if s not in self.active]
@@ -174,12 +195,14 @@ class ServingEngine:
                 pad = (-plen) % self.bucket
                 toks = jnp.asarray([0] * pad + req.prompt,
                                    jnp.int32)[None, :]
-                with self._span("engine.prefill"):
+                with self._span("engine.prefill") as prefill_span:
                     slot_cache, logits = self._prefill(self.params,
                                                        {"tokens": toks})
                 with self._span("engine.splice"):
                     self._splice_slot(slot, slot_cache)
-                nxt = int(jnp.argmax(logits[0]))
+                nxt, load = jax.device_get((jnp.argmax(logits[0]),
+                                            self._load(slot_cache)))
+                nxt = int(nxt)
                 req.out_tokens.append(nxt)
                 self.last_token = self.last_token.at[slot, 0].set(nxt)
                 self.live[slot] = True
@@ -187,6 +210,7 @@ class ServingEngine:
             if tr is not None:
                 tr.metrics.counter("admitted").inc()
                 tr.metrics.counter("prefill_tokens").inc(plen + pad)
+                self._record_load(prefill_span, load)
 
     def step(self) -> list[Request]:
         """Admit waiting requests, run the aux offload admission pass (a
@@ -205,12 +229,13 @@ class ServingEngine:
                     self.flush_aux()
             if not self.active:
                 return []
-            with self._span("engine.decode"):
+            with self._span("engine.decode") as decode_span:
                 logits, self.cache = self._decode(self.params, self.cache,
                                                   self.last_token)
             finished = []
             with self._span("engine.sample"):
-                pos_host = jax.device_get(self.cache["pos"])
+                pos_host, load = jax.device_get((self.cache["pos"],
+                                                 self._load(self.cache)))
                 lanes = list(self.active.items())
                 for slot, req in lanes:
                     nxt = int(jnp.argmax(logits[slot]))
@@ -228,6 +253,7 @@ class ServingEngine:
                 m.counter("decode_steps").inc()
                 m.counter("decode_positions").inc(
                     sum(int(pos_host[slot]) for slot, _ in lanes))
+                self._record_load(decode_span, load)
             return finished
 
     def run_to_completion(self, max_steps: int = 10_000) -> list[Request]:
