@@ -37,22 +37,25 @@ spans share one timeline — each step records lexical spans on lane
                    (``engine.splice``) and its first-token argmax
     engine.aux     the offload poll or flush
     engine.decode  the dispatch of ``decode_step``
-    engine.sample  the positions' ``device_get``, the per-lane argmaxes
-                   and ``last_token`` updates
+    engine.sample  the dispatch of ``sample_greedy`` (every lane's argmax
+                   and its ``last_token`` in one program), the step's one
+                   ``device_get`` (tokens, positions) and the host's
+                   EOS, length and cache-full checks
 
 plus a retrospective ``queued`` span per request (``submit`` to the start
 of its admission) and the counters ``decode_steps``, ``admitted``,
-``prefill_tokens`` (left padding included) and ``decode_positions`` (the
-positions each decode step attended, summed over its live lanes) in
-``tracer.metrics``.  A model with experts also annotates each
+``prefill_tokens`` (left padding included), ``decode_positions`` (the
+positions each decode step attended, summed over its live lanes) and
+``sampled_lanes`` (the live lanes whose token came from ``sample_greedy``)
+in ``tracer.metrics``.  A model with experts also annotates each
 ``engine.prefill`` and ``engine.decode`` span with the expert layers' load
 and counts it: ``expert_tokens`` (the (token, held expert) pairs
 computed), ``experts_reached`` (held experts of all expert layers that
 got a token) and ``max_expert_tokens`` (the most pairs one held expert
 got).  The load comes back in the fetch the step already makes (the
-first-token argmax at admission, the positions after a decode).  No span
-adds a host-device sync; without a tracer no span site calls into the
-tracer or the profiler.
+first-token argmax at admission, the tokens and positions after a
+decode).  No span adds a host-device sync; without a tracer no span site
+calls into the tracer or the profiler.
 """
 
 from __future__ import annotations
@@ -63,12 +66,23 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels.common import program_name
 from repro.models.config import ModelConfig
 from repro.models.model import LM
 
 __all__ = ["Request", "ServingEngine"]
+
+
+@program_name("sample_greedy")
+def _sample_greedy(logits: jax.Array, last_token: jax.Array,
+                   live: jax.Array):
+    """Greedy tokens of every lane, ``(B,)`` int32 (first index on ties),
+    and the next decode input: the token where the lane is live, its last
+    token where it is not."""
+    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return nxt, jnp.where(live[:, None], nxt[:, None], last_token)
 
 
 @dataclasses.dataclass
@@ -99,6 +113,7 @@ class ServingEngine:
         self.live = [False] * batch_slots
         self._decode = jax.jit(program_name("decode_step")(
             lambda p, c, t: self.model.decode_step(p, c, t)))
+        self._sample = jax.jit(_sample_greedy)
         self._prefill = jax.jit(
             lambda p, b: self.model.prefill(p, b, max_len=max_len))
         # analog-offload hook: an OffloadScheduler / PlanRouter /
@@ -234,14 +249,15 @@ class ServingEngine:
                                                   self.last_token)
             finished = []
             with self._span("engine.sample"):
-                pos_host, load = jax.device_get((self.cache["pos"],
-                                                 self._load(self.cache)))
+                nxt, self.last_token = self._sample(
+                    logits, self.last_token, np.asarray(self.live))
+                nxt_host, pos_host, load = jax.device_get(
+                    (nxt, self.cache["pos"], self._load(self.cache)))
                 lanes = list(self.active.items())
                 for slot, req in lanes:
-                    nxt = int(jnp.argmax(logits[slot]))
-                    req.out_tokens.append(nxt)
-                    self.last_token = self.last_token.at[slot, 0].set(nxt)
-                    if (self.eos_id is not None and nxt == self.eos_id) \
+                    tok = int(nxt_host[slot])
+                    req.out_tokens.append(tok)
+                    if (self.eos_id is not None and tok == self.eos_id) \
                             or len(req.out_tokens) >= req.max_new_tokens \
                             or int(pos_host[slot]) >= self.max_len - 1:
                         req.done = True
@@ -251,6 +267,7 @@ class ServingEngine:
             if self.tracer is not None:
                 m = self.tracer.metrics
                 m.counter("decode_steps").inc()
+                m.counter("sampled_lanes").inc(len(lanes))
                 m.counter("decode_positions").inc(
                     sum(int(pos_host[slot]) for slot, _ in lanes))
                 self._record_load(decode_span, load)

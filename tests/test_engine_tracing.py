@@ -92,9 +92,12 @@ def test_engine_counters(model, bucket):
     positions = sum(p + k for p, r in zip(padded, reqs)
                     for k in range(1, len(r.out_tokens)))
     decodes = [s for s in tracer.spans() if s.name == "engine.decode"]
+    # every token after a request's first comes from the batched sampler
+    sampled = sum(len(r.out_tokens) - 1 for r in reqs)
     assert got == {"admitted": len(reqs), "prefill_tokens": sum(padded),
                    "decode_steps": len(decodes),
-                   "decode_positions": positions}
+                   "decode_positions": positions,
+                   "sampled_lanes": sampled}
 
 
 def test_engine_records_on_its_offload_runtime_tracer(model):
@@ -119,13 +122,16 @@ def test_engine_records_on_its_offload_runtime_tracer(model):
 
 
 def test_untraced_engine_never_calls_the_tracer(model, monkeypatch):
-    from repro.runtime import tracing
+    from repro.runtime import metrics, tracing
 
     def refuse(*a, **k):
         raise AssertionError("tracer or profiler called without a tracer")
 
     monkeypatch.setattr(tracing, "TraceAnnotation", refuse)
-    monkeypatch.setattr(tracing.Tracer, "now", refuse)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    for method in ("now", "span", "begin", "record"):
+        monkeypatch.setattr(tracing.Tracer, method, refuse)
+    monkeypatch.setattr(metrics.MetricsRegistry, "counter", refuse)
     engine, reqs = _serve(model)
     assert engine.tracer is None
     assert all(r.out_tokens for r in reqs)
