@@ -4,13 +4,13 @@ Prints ``name,us_per_call,derived`` CSV rows per the harness contract:
   * Table 1 / Fig 9 rows: us_per_call = benchmark total time, derived =
     ideal end-to-end Amdahl speedup (paper value appended for comparison);
   * Fig 8: hardware-vs-software ratio; Fig 2: frontier gaps; Fig 3:
-    complexity crossovers; planner: per-arch bounded speedups;
-  * roofline rows when dry-run artifacts exist.
+    complexity crossovers; planner: per-arch bounded speedups.
+
+Timings of the offload runtime come from the chip benchmark
+(``benchmarks/chip/run.py``), not from here.
 """
 
 from __future__ import annotations
-
-import sys
 
 
 def main() -> None:
@@ -69,34 +69,6 @@ def main() -> None:
               f"|matmul_flops={mm:.1f}%"
               f"|worthwhile={row['mvm_worthwhile']}"
               f"|conversion_bound={row['mvm_conversion_bound']}")
-
-    # --- Offload runtime: batching amortization + telemetry round trip ---------------
-    # Also writes BENCH_runtime.json (per-batch-size wall/boundary seconds
-    # per call + batched-vs-looped speedup, and the trickle-arrival
-    # continuous-batching column with its scheduler config — deadline,
-    # arrival rate, seed — stamped alongside the measured occupancies) so
-    # the perf trajectory is machine-readable AND interpretable across PRs.
-    from benchmarks.runtime_bench import run as runtime_bench, write_json
-    payload = write_json()
-    for row in runtime_bench(payload):
-        print(row)
-
-    # --- Roofline (needs dry-run artifacts) -------------------------------------------
-    import os
-    try:
-        from benchmarks.roofline import ART_DIR, run as roofline
-        for tag, d in (("roofline", ART_DIR),
-                       ("roofline_opt", os.path.join(ART_DIR, "..",
-                                                     "dryrun_opt"))):
-            if not os.path.isdir(d):
-                continue
-            for r in roofline(d):
-                print(f"{tag},{r['cell']},"
-                      f"{1e6 * r['step_lower_bound_s']:.1f},"
-                      f"dominant={r['dominant']}|useful={r['useful_ratio']:.3f}"
-                      f"|roof={100*r['roofline_fraction']:.1f}%")
-    except Exception as e:  # artifacts absent: non-fatal
-        print(f"roofline,error,,{type(e).__name__}")
 
 
 if __name__ == "__main__":

@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import importlib.util
 import json
 import pathlib
 import sys
@@ -84,17 +85,18 @@ def report(phase: str, **values) -> None:
 # --- references on the host, float64 -----------------------------------------
 
 
-def quantize(x, bits: int):
-    levels = (1 << bits) - 1
-    return np.round(np.clip(x, 0.0, 1.0) * levels) / levels
+def _load_reference():
+    """The benchmark's float64 4f reference (DAC quantize, unitary 2-D DFT,
+    square-law detector, auto-ranged ADC), loaded from its file."""
+    path = ROOT / "benchmarks" / "chip" / "references" / "optical4f.py"
+    spec = importlib.util.spec_from_file_location("optical4f_reference",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
-def fft_reference(frame, spec):
-    """DAC quantize, unitary 2-D DFT, square-law detector, auto-ranged ADC."""
-    a = quantize(np.asarray(frame, np.float64), spec.dac.bits)
-    intensity = np.abs(np.fft.fft2(a, norm="ortho")) ** 2
-    scale = max(float(intensity.max()), 1e-20)
-    return quantize(intensity / scale, spec.adc.bits) * scale
+fft_reference = _load_reference().fft_reference
 
 
 def conv_reference(frame, kernel):
@@ -189,7 +191,7 @@ def fft_errors(handles, host, spec) -> dict:
     float64 reference."""
     worst = {"rel_err": 0.0, "code_gap": 0, "full_scale_gap": 0.0}
     for h, frame in zip(handles, host):
-        ref = fft_reference(frame, spec)
+        ref = fft_reference(frame, (spec.dac.bits, spec.adc.bits))
         got = h.value
         worst["rel_err"] = max(worst["rel_err"], rel_err(got, ref))
         gap, scale_gap = adc_code_gap(got, ref, spec.adc.bits)
@@ -411,7 +413,9 @@ def sharded_phase(sizes: Sizes, seed: int, devices) -> None:
            devices_observed=four.telemetry.devices_observed("fft"),
            dispatches=stats.invocations,
            tile_sizes=four.telemetry.tile_sizes_observed("fft"),
-           residency=dict(four.telemetry.residency_counts.get("fft", {})),
+           residency={labels["event"]: c.value for labels, c in
+                      four.telemetry.metrics.select("residency",
+                                                    category="fft")},
            max_abs_diff_vs_one_device=diff, fft_rel_err=worst["rel_err"],
            enob_bound=enob_bound(spec),
            peak_bytes_in_use=[peak_bytes(d) for d in devices])

@@ -21,9 +21,8 @@ that runs it.  Module map:
                (``set_pipeline_window``): one engine's in-flight depth
                never gates another's, retirement stays submit-ordered
                within each engine, and the global ``pipeline_depth``
-               remains the back-compat default for unpinned categories
-               (``shared_window=True`` restores the old single gate),
-               with per-category coalescing ceilings (``set_max_batch``),
+               remains the default for unpinned categories, with
+               per-category coalescing ceilings (``set_max_batch``),
                per-shape DFT-factor / Fourier-mask / jit caches, a public
                group-release primitive (``release``) the scheduler drives,
                and context-manager cleanup (``with`` drains queued, held,
@@ -40,7 +39,10 @@ that runs it.  Module map:
   telemetry  — ``RuntimeTelemetry``: measured per-category call counts,
                sample counts, wall time, and the submit arrival process
                (``arrival_rate``), emitted as ``CategoryProfile``s so
-               ``plan_offload`` re-plans from observed traffic.
+               ``plan_offload`` re-plans from observed traffic; every
+               runtime event (faults, recoveries, windows, residency,
+               deltas, per-device traffic) counted once in its
+               ``metrics`` registry, and traced when a tracer is attached.
   fidelity   — ``FidelityChecker``: shadows optical-sim batches with the
                host reference (vectorized: one norm reduction + one sync
                per batch; ``sample_every`` bounds hot-path cost) and scores
@@ -115,7 +117,9 @@ that runs it.  Module map:
                sharded dispatch.  Zero overhead when off; injectable
                clock (``ManualClock``) for exact test assertions.
   metrics    — ``Counter`` / ``Histogram`` / ``MetricsRegistry``
-               (mergeable log-binned percentile histograms).
+               (mergeable log-binned percentile histograms): the runtime's
+               counters live in ``telemetry.metrics``, the serving
+               engine's in ``tracer.metrics``.
   trace_export — Chrome/Perfetto ``trace_event`` JSON export
                (``write_trace``), per-stage charged sums
                (``stage_sums`` / ``reconcile``), one-screen digests
@@ -180,10 +184,7 @@ from repro.runtime.sharded import ShardedOpticalBackend, kernel_halo, shard_size
 from repro.runtime.specs import BATCHED_4F, CAMERA_ADC, SLM_DAC
 from repro.runtime.telemetry import (
     BackendStats,
-    DeltaStats,
-    DeviceStats,
     RuntimeTelemetry,
-    WindowStats,
 )
 from repro.runtime.tiling import (
     BlockPlan,
@@ -242,10 +243,7 @@ __all__ = [
     "shard_sizes",
     "BackendStats",
     "DELTA_THRESHOLD",
-    "DeltaStats",
-    "DeviceStats",
     "RuntimeTelemetry",
-    "WindowStats",
     "BlockPlan",
     "MemoryBudget",
     "TilePlan",

@@ -74,6 +74,7 @@ from repro.kernels.optical_dft import (
     dft_stage2_batched,
 )
 from repro.runtime.residency import residency_key
+from repro.runtime.telemetry import RuntimeTelemetry
 from repro.runtime.tiling import BlockPlan, MemoryBudget, choose_blocks
 
 __all__ = [
@@ -159,13 +160,17 @@ class BackendContext:
     # DeviceLostError and recovers on a survivor.  Cleared by the injector.
     lost_devices: frozenset = frozenset()
     # Fault-handling collaborators (duck-typed like ``tracer`` to keep
-    # backends importable without the faults/telemetry modules): the
-    # executor's Quarantine (sharded dispatch skips quarantined devices and
-    # records new exclusions here), its DispatchWatchdog (per-device
-    # straggler deadlines), and its RuntimeTelemetry (fault counters).
+    # backends importable without the faults module): the executor's
+    # Quarantine (sharded dispatch skips quarantined devices and records
+    # new exclusions here) and its DispatchWatchdog (per-device straggler
+    # deadlines).
     quarantine: "object | None" = None
     watchdog: "object | None" = None
-    telemetry: "object | None" = None
+    # The owning executor's RuntimeTelemetry: backends record their fault,
+    # quarantine, placement, residency and delta events through it (a
+    # context built alone gets a telemetry of its own).
+    telemetry: RuntimeTelemetry = dataclasses.field(
+        default_factory=RuntimeTelemetry)
     # The owning executor's operand residency cache
     # (``repro.runtime.residency.ResidencyCache``), or None for the
     # historical stage-every-flush behavior.  With a cache attached, the

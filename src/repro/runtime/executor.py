@@ -199,7 +199,6 @@ class OffloadExecutor:
       spec: accelerator priced/simulated by the analog backends.
       default_backend: where submits go when the caller (or router) does
         not name one.
-      telemetry: shared :class:`RuntimeTelemetry` (created if omitted).
       fidelity: optional :class:`FidelityChecker`; when set, optical-sim
         batches are shadowed by the host backend and scored (validation
         mode — the shadow run is excluded from telemetry, and fidelity
@@ -217,13 +216,8 @@ class OffloadExecutor:
         group k+1 stages while group k computes.  1 restores strictly
         serial dispatch-then-block crossings per engine.  Per-category
         depths (``set_pipeline_window``) let the router adapt window depth
-        per engine; the global value is the default/back-compat alias
-        every unpinned category inherits.
-      shared_window: ``True`` restores the pre-per-engine discipline — ONE
-        global ``pipeline_depth``-deep window shared by every engine, so
-        dispatching any invocation retires the globally oldest one
-        regardless of engine.  The measured baseline per-engine windows
-        are benched against.
+        per engine; the global value is the default every unpinned
+        category inherits.
       n_devices: how many replicated simulated accelerators the ``sharded``
         backend scatters each invocation across.  A global ceiling;
         per-category counts (``set_n_devices``) let the router adapt the
@@ -271,13 +265,14 @@ class OffloadExecutor:
         host staging and are priced read-side-only
         (``batched_step_cost(resident_frames=...)``), sharded dispatch
         keeps per-device resident shard sets, and hit/miss/eviction
-        counters land in telemetry (``residency_counts``) and the trace
-        (``cache`` instants).
+        counters land in telemetry (``residency{category, event}``) and
+        the trace (``cache`` instants).
       tracer: optional :class:`~repro.runtime.tracing.Tracer`.  When set,
         every dispatch emits a boundary-attributed span tree (submit ->
         held -> release -> invocation -> stage -> compute ->
         fidelity-shadow, with per-device scatter children under sharded
-        dispatch) plus counters/histograms in ``tracer.metrics``.  The
+        dispatch); fault, fallback and quarantine events reach it through
+        ``telemetry``, which counts them with or without a tracer.  The
         default ``None`` is a measured no-op: instrumentation sites guard
         on the attribute and add no dispatch work.  For exact span
         durations in tests, give the tracer the same ``ManualClock`` as
@@ -292,7 +287,6 @@ class OffloadExecutor:
                        OpticalMVMAcceleratorSpec = PROTOTYPE_4F,
                  *,
                  default_backend: str = "optical-sim",
-                 telemetry: RuntimeTelemetry | None = None,
                  fidelity: FidelityChecker | None = None,
                  max_batch: int = 32,
                  pipeline_depth: int = 2,
@@ -300,7 +294,6 @@ class OffloadExecutor:
                  shard_mode: str = "auto",
                  mem_budget: MemoryBudget | None = None,
                  tile_k: int | None = None,
-                 shared_window: bool = False,
                  clock: Callable[[], float] = time.perf_counter,
                  retry: RetryPolicy | None = None,
                  residency: "ResidencyCache | bool | None" = None,
@@ -317,13 +310,13 @@ class OffloadExecutor:
             raise ValueError("tile_k must be >= 1")
         if mem_budget is None:
             mem_budget = MemoryBudget.detect()
+        self.telemetry = RuntimeTelemetry(tracer=tracer)
         self.ctx = BackendContext(spec=spec, pipeline_depth=pipeline_depth,
                                   n_devices=n_devices, shard_mode=shard_mode,
                                   mem_budget=mem_budget, tracer=tracer,
-                                  clock=clock)
+                                  clock=clock, telemetry=self.telemetry)
         self.tracer = tracer
         self.default_backend = default_backend
-        self.telemetry = telemetry or RuntimeTelemetry()
         self.fidelity = fidelity
         self.retry = retry or RetryPolicy()
         self.quarantine = Quarantine(window_s=self.retry.quarantine_s,
@@ -338,7 +331,6 @@ class OffloadExecutor:
         # the sharded backend quarantines devices through the same policy
         self.ctx.quarantine = self.quarantine
         self.ctx.watchdog = self._watchdog
-        self.ctx.telemetry = self.telemetry
         if residency is True:
             residency = ResidencyCache(mem_budget)
         elif residency is False:
@@ -350,7 +342,6 @@ class OffloadExecutor:
         self.n_devices = n_devices
         self.mem_budget = mem_budget
         self.tile_k = tile_k
-        self.shared_window = shared_window
         self._category_max_batch: dict[str, int] = {}
         self._category_n_devices: dict[str, int] = {}
         self._category_tile_k: dict[str, int] = {}
@@ -418,9 +409,8 @@ class OffloadExecutor:
     def pipeline_window_for(self, category: str) -> int:
         """Effective in-flight window depth for ``category``'s engine.  The
         global ``pipeline_depth`` is the default every unpinned category
-        inherits — the back-compat alias: with no pins and
-        ``shared_window=False`` a single-category workload behaves exactly
-        like the historical global window."""
+        inherits: with no pins a single-category workload behaves exactly
+        like one global window."""
         return max(1, self._category_window.get(category,
                                                 self.pipeline_depth))
 
@@ -656,6 +646,7 @@ class OffloadExecutor:
         # so priming stacks neither pollute the resident set nor skew the
         # hit-rate ledger the router replans from
         saved, self.ctx.tracer = self.ctx.tracer, None
+        self.telemetry.tracer = None
         saved_wd, self.ctx.watchdog = self.ctx.watchdog, None
         saved_res, self.ctx.residency = self.ctx.residency, None
         try:
@@ -664,7 +655,7 @@ class OffloadExecutor:
                                  kernel=kernel, weights=weights)
                 _block(outs)
         finally:
-            self.ctx.tracer = saved
+            self.ctx.tracer = self.telemetry.tracer = saved
             self.ctx.watchdog = saved_wd
             self.ctx.residency = saved_res
             self.ctx.n_devices = saved_nd
@@ -733,13 +724,13 @@ class OffloadExecutor:
             return []
         chosen = set(map(id, members))
         self._queue = [p for p in self._queue if id(p) not in chosen]
-        tr = self.tracer
+        self.telemetry.count("release", reason=reason)
         scope = contextlib.nullcontext()
-        if tr is not None:
-            scope = tr.span("release", lane="sched", kind="async",
-                            reason=reason, category=members[0].category,
-                            count=len(members))
-            tr.metrics.counter("release", reason=reason).inc()
+        if self.tracer is not None:
+            scope = self.tracer.span("release", lane="sched", kind="async",
+                                     reason=reason,
+                                     category=members[0].category,
+                                     count=len(members))
         done: list[OffloadResult] = []
         cap = self.max_batch_for(members[0].category)
         with scope as rel:
@@ -791,21 +782,14 @@ class OffloadExecutor:
         Retirement is in dispatch order *within the result's engine window*
         (category, backend) — the per-engine DAG discipline: waiting on an
         fft result must not block-and-bill an unrelated conv engine's
-        still-computing window.  ``shared_window=True`` restores the
-        historical global dispatch-order drain."""
+        still-computing window."""
         target = next((g for g in self._inflight
                        if any(p.result is result for p in g.chunk)), None)
         if target is None:
             return
-        while self._inflight:
-            if self.shared_window:
-                g = self._inflight.popleft()
-            else:
-                g = next((g for g in self._inflight
-                          if g.wkey == target.wkey), None)
-                if g is None:
-                    return
-                self._inflight.remove(g)
+        while True:
+            g = next(g for g in self._inflight if g.wkey == target.wkey)
+            self._inflight.remove(g)
             self._retire(g)
             if g is target:
                 return
@@ -880,12 +864,8 @@ class OffloadExecutor:
         fb = self._backend(policy.fallback)
         if not fb.supports(category, self.ctx):
             return be
-        self.telemetry.note_fault(category, "reroute")
-        if self.tracer is not None:
-            self.tracer.instant("fallback", lane="sched", category=category,
-                                backend=be.name, to=fb.name,
-                                reason="quarantined")
-            self.tracer.metrics.counter("reroutes", category=category).inc()
+        self.telemetry.fallback(category, kind="reroute", backend=be.name,
+                                to=fb.name, reason="quarantined")
         return fb
 
     def _run_guarded(self, be: ExecutionBackend, head: _Pending,
@@ -906,6 +886,7 @@ class OffloadExecutor:
         quarantine a healthy backend).
         """
         tr = self.tracer
+        tel = self.telemetry
         cat = head.category
         policy = self.retry
         t_first_fault: float | None = None
@@ -918,70 +899,43 @@ class OffloadExecutor:
             except FaultError as e:
                 if t_first_fault is None:
                     t_first_fault = t0
-                self.telemetry.note_fault(cat, e.kind)
-                if tr is not None:
-                    tr.instant("fault", lane="sched", parent=parent,
-                               category=cat, backend=be.name, kind=e.kind,
-                               attempt=attempt)
-                    tr.metrics.counter("faults", category=cat,
-                                       kind=e.kind).inc()
-                if attempt >= policy.max_attempts:
-                    break
-                backoff = policy.backoff_for(attempt)
-                rt0 = tr.now() if tr is not None else 0.0
-                advance_or_sleep(self._clock, backoff)
-                if tr is not None:
-                    tr.record("retry", rt0, tr.now(), lane="sched",
-                              kind="async", parent=parent, category=cat,
-                              backend=be.name, attempt=attempt,
-                              backoff_s=backoff)
-                    tr.metrics.counter("retries", category=cat,
-                                       backend=be.name).inc()
+                tel.fault(cat, e.kind, parent=parent, backend=be.name,
+                          attempt=attempt)
+                if attempt < policy.max_attempts:
+                    backoff = policy.backoff_for(attempt)
+                    rt0 = tr.now() if tr is not None else 0.0
+                    advance_or_sleep(self._clock, backoff)
+                    if tr is not None:
+                        tr.record("retry", rt0, tr.now(), lane="sched",
+                                  kind="async", parent=parent, category=cat,
+                                  backend=be.name, attempt=attempt,
+                                  backoff_s=backoff)
+                    tel.count("retries", category=cat, backend=be.name)
                 continue
             elapsed = self._clock() - t0
             base = modeled.total_s if modeled is not None else None
             if self._watchdog.observe((cat, be.name), elapsed, base):
-                self.telemetry.note_fault(cat, "straggle")
-                if tr is not None:
-                    tr.instant("fault", lane="sched", parent=parent,
-                               category=cat, backend=be.name,
-                               kind="straggle", elapsed_s=elapsed)
-                    tr.metrics.counter("faults", category=cat,
-                                       kind="straggle").inc()
+                tel.fault(cat, "straggle", parent=parent, backend=be.name,
+                          elapsed_s=elapsed)
             else:
                 self.quarantine.note_healthy(("category", cat))
-            if t_first_fault is not None:
-                dt = self._clock() - t_first_fault
-                self.telemetry.note_recovery(cat, dt)
-                if tr is not None:
-                    tr.metrics.histogram("recovery_s",
-                                         category=cat).record(dt)
-            return outs, modeled, be
-        # every attempt faulted: graceful degradation — the fallback is
-        # always correct, so the caller still gets its results in order
-        fb = self._backend(policy.fallback)
-        ev = self.quarantine.quarantine(("category", cat), self._clock(),
-                                        reason="retry-exhausted")
-        self.telemetry.note_fault(cat, "fallback")
-        if tr is not None:
-            tr.instant("fallback", lane="sched", parent=parent,
-                       category=cat, backend=be.name, to=fb.name,
-                       reason="retry-exhausted")
-            q0 = tr.now()
-            tr.record("quarantine", q0, q0 + (ev.until - ev.t), lane="sched",
-                      kind="async", parent=parent, key=str(ev.key),
-                      reason=ev.reason, level=ev.level)
-            tr.metrics.counter("fallbacks", category=cat,
-                               backend=be.name).inc()
-            tr.metrics.counter("quarantines", reason=ev.reason).inc()
-        outs, modeled = fb.run(cat, xs, self.ctx, kernel=head.kernel,
-                               weights=head.weights)
+            break
+        else:
+            # every attempt faulted: graceful degradation — the fallback
+            # is always correct, so the caller still gets its results in
+            # order
+            fb = self._backend(policy.fallback)
+            ev = self.quarantine.quarantine(("category", cat), self._clock(),
+                                            reason="retry-exhausted")
+            tel.fallback(cat, backend=be.name, to=fb.name,
+                         reason="retry-exhausted", parent=parent)
+            tel.quarantined(ev, parent=parent)
+            outs, modeled = fb.run(cat, xs, self.ctx, kernel=head.kernel,
+                                   weights=head.weights)
+            be = fb
         if t_first_fault is not None:
-            dt = self._clock() - t_first_fault
-            self.telemetry.note_recovery(cat, dt)
-            if tr is not None:
-                tr.metrics.histogram("recovery_s", category=cat).record(dt)
-        return outs, modeled, fb
+            tel.recovered(cat, self._clock() - t_first_fault)
+        return outs, modeled, be
 
     def _dispatch_invocation(self, chunk: list[_Pending], *,
                              reason: str = "flush",
@@ -992,21 +946,15 @@ class OffloadExecutor:
         # rather than unbounded (frame buffers are finite), and it blocks
         # on that engine's *oldest* invocation while this chunk's host-side
         # staging below overlaps it.  Engines gate independently — a full
-        # fft window never forces a conv retirement (shared_window=True
-        # restores the historical single global window).
+        # fft window never forces a conv retirement.
         head = chunk[0]
         wkey = (head.category, head.backend)
-        if self.shared_window:
-            depth = self.pipeline_depth
-            while len(self._inflight) >= depth:
-                self._retire(self._inflight.popleft())
-        else:
-            depth = self.pipeline_window_for(head.category)
-            while sum(1 for g in self._inflight if g.wkey == wkey) >= depth:
-                self._retire_matching(wkey)
+        depth = self.pipeline_window_for(head.category)
+        while sum(1 for g in self._inflight if g.wkey == wkey) >= depth:
+            self._retire_matching(wkey)
         occupancy = 1 + sum(1 for g in self._inflight if g.wkey == wkey)
         self.telemetry.note_window(head.category, head.backend,
-                                   in_flight=occupancy, depth=depth)
+                                   in_flight=occupancy)
         be = self._reroute_quarantined(head.category,
                                        self._backend(head.backend))
         xs = [p.x for p in chunk]
@@ -1040,8 +988,6 @@ class OffloadExecutor:
                           lane="sched", kind="async", parent=inv,
                           reason=reason, category=head.category,
                           hold_s=hold_s)
-            tr.metrics.counter("invocations", category=head.category,
-                               backend=head.backend).inc()
         t0 = time.perf_counter()
         if tr is not None:
             # lexical: backend-side spans (sharded per-device scatter /
@@ -1155,9 +1101,6 @@ class OffloadExecutor:
             f.span.annotate(wall_s=wall, stage_s=stage_charged,
                             compute_s=compute_charged, hold_s=f.hold_s,
                             shadow_s=0.0, deferred=already_done)
-            tr.metrics.histogram(
-                "invocation_wall_s", category=f.chunk[0].category,
-                backend=f.be.name).record(wall)
         report = None
         if f.shadow:
             t1 = time.perf_counter()
@@ -1202,30 +1145,17 @@ class OffloadExecutor:
                 ev = self.quarantine.quarantine(("category", cat),
                                                 self._clock(),
                                                 reason="fidelity-drift")
-                self.telemetry.note_fault(cat, "drift")
-                self.telemetry.note_recovery(cat, dt)
                 for p, ref in zip(f.chunk, refs):
                     p.result.value = ref
                     p.result.backend = self.retry.fallback
-                if tr is not None and f.span is not None:
-                    tr.instant("fault", lane="sched", parent=f.span,
-                               category=cat, backend=f.be.name,
-                               kind="drift", rel_err=report.rel_err,
-                               bound=report.bound)
-                    tr.instant("fallback", lane="sched", parent=f.span,
-                               category=cat, backend=f.be.name,
-                               to=self.retry.fallback, reason="drift")
-                    q0 = tr.now()
-                    tr.record("quarantine", q0, q0 + (ev.until - ev.t),
-                              lane="sched", kind="async", parent=f.span,
-                              key=str(ev.key), reason=ev.reason,
-                              level=ev.level)
-                    tr.metrics.counter("faults", category=cat,
-                                       kind="drift").inc()
-                    tr.metrics.counter("quarantines",
-                                       reason=ev.reason).inc()
-                    tr.metrics.histogram("recovery_s",
-                                         category=cat).record(dt)
+                tel = self.telemetry
+                tel.fault(cat, "drift", parent=f.span, backend=f.be.name,
+                          rel_err=report.rel_err, bound=report.bound)
+                tel.fallback(cat, kind=None, backend=f.be.name,
+                             to=self.retry.fallback, reason="drift",
+                             parent=f.span)
+                tel.quarantined(ev, parent=f.span)
+                tel.recovered(cat, dt)
         if f.modeled is None:
             # refine the provisional dispatch-only share to the measured
             # wall (the hold share survives the refinement: queueing delay

@@ -1,10 +1,11 @@
 """Counters and streaming percentile histograms.
 
 A tiny metrics kernel (:class:`Counter`, :class:`Histogram`,
-:class:`MetricsRegistry`) with the same mergeability contract
-``RuntimeTelemetry.merge`` has: histograms use *fixed log-spaced bins*, so
-merging two histograms is exact bin-count addition (associative,
-commutative) — per-worker registries roll up without resampling.
+:class:`MetricsRegistry`) behind ``RuntimeTelemetry.metrics`` and
+``Tracer.metrics``, mergeable across workers: histograms use *fixed
+log-spaced bins*, so merging two histograms is exact bin-count addition
+(associative, commutative) — per-worker registries roll up without
+resampling.
 Percentiles (p50/p95/p99) come from a cumulative walk over the bins; the
 answer is the geometric midpoint of the rank's bin, clamped to the
 observed [min, max], so a single-sample histogram reports the sample
@@ -158,16 +159,55 @@ class MetricsRegistry:
         self._hists: dict[tuple, Histogram] = {}
 
     def counter(self, name: str, **labels: Any) -> Counter:
-        return self._counters.setdefault(_key(name, labels), Counter())
+        key = _key(name, labels)
+        c = self._counters.get(key)
+        if c is None:
+            c = self._counters[key] = Counter()
+        return c
 
     def histogram(self, name: str, **labels: Any) -> Histogram:
-        return self._hists.setdefault(_key(name, labels), Histogram())
+        key = _key(name, labels)
+        h = self._hists.get(key)
+        if h is None:
+            h = self._hists[key] = Histogram()
+        return h
 
     def counters(self) -> dict[tuple, int]:
         return {k: c.value for k, c in sorted(self._counters.items())}
 
     def histograms(self) -> dict[tuple, Histogram]:
         return dict(self._hists)
+
+    def select(self, name: str, **match: Any,
+               ) -> list[tuple[dict[str, str], Counter | Histogram]]:
+        """``(labels, metric)`` for every counter or histogram called
+        ``name`` whose labels include ``match`` (compared as strings; a
+        ``None`` value matches any), in recording order."""
+        want = {k: str(v) for k, v in match.items() if v is not None}
+        out = []
+        for store in (self._counters, self._hists):
+            for key, m in store.items():
+                if key[0] != name:
+                    continue
+                labels = dict(key[1:])
+                if all(labels.get(k) == v for k, v in want.items()):
+                    out.append((labels, m))
+        return out
+
+    def total(self, name: str, **match: Any) -> int:
+        """Sum of the matching counters (0 when none match)."""
+        return sum(c.value for _, c in self.select(name, **match))
+
+    def merged(self, name: str, **match: Any) -> Histogram | None:
+        """A merged copy of the matching histograms; ``None`` when none
+        match."""
+        out: Histogram | None = None
+        for _, h in self.select(name, **match):
+            if out is None:
+                out = h.copy()
+            else:
+                out.merge(h)
+        return out
 
     def merge(self, other: "MetricsRegistry") -> None:
         for k, c in other._counters.items():

@@ -36,8 +36,8 @@ so a resident operand is near-free on the write path.
     :meth:`ResidencyCache.effective_budget` hands the executor the budget
     *minus* resident bytes so tile depth shrinks as the cache fills.
   * **Observable.**  Every lookup/store/eviction/invalidation is counted
-    per category (mirrored into ``RuntimeTelemetry.residency_counts`` and
-    emitted as ``cache`` instants on the tracer when either is attached),
+    per category (mirrored into the context's ``RuntimeTelemetry`` as
+    ``residency{category, event}`` and, traced, as ``cache`` instants),
     so hit rates are first-class telemetry the router can replan from.
 
 The cache is OPT-IN (``OffloadExecutor(residency=...)``): with it off the
@@ -164,14 +164,10 @@ class ResidencyCache:
         self.counts[category][event] += 1
         if ctx is None:
             return
-        tel = getattr(ctx, "telemetry", None)
-        note = getattr(tel, "note_residency", None)
-        if note is not None:
-            note(category, event)
-        tr = getattr(ctx, "tracer", None)
-        if tr is not None:
-            tr.instant("cache", lane="host", category=category, event=event,
-                       **attrs)
+        ctx.telemetry.note_residency(category, event)
+        if ctx.tracer is not None:
+            ctx.tracer.instant("cache", lane="host", category=category,
+                               event=event, **attrs)
 
     # -- the cache proper ------------------------------------------------------
     def lookup(self, device: Hashable, key: tuple, *, category: str,
@@ -229,8 +225,8 @@ class ResidencyCache:
         signature decides delta (≤ ``delta_threshold``, priced at
         :func:`~repro.core.conversion.delta_write_scale`) versus full.
         Every outcome updates the slot ledger; delta/full writes are
-        mirrored into ``RuntimeTelemetry.delta_stats`` when the context
-        carries telemetry.  Classification never touches the LRU — it is
+        mirrored into the context's ``RuntimeTelemetry`` (``delta_flip`` /
+        ``delta_full``).  Classification never touches the LRU — it is
         the *write-side* price of an operand the group-grain lookup
         already missed."""
         prev = self._slots.get(slot_key)
@@ -241,20 +237,15 @@ class ResidencyCache:
         if slot_key not in self._slots and len(self._slots) >= SLOT_LEDGER_MAX:
             self._slots.clear()
         self._slots[slot_key] = (ck, sig)
-        tel = getattr(ctx, "telemetry", None) if ctx is not None else None
-        note = getattr(tel, "note_delta", None)
-        if prev is None:
-            if note is not None:
-                note(category)
-            return "full", 1.0
-        frac = expected_flip_fraction(prev[1], sig)
-        if frac > self.delta_threshold:
-            if note is not None:
-                note(category)
+        tel = ctx.telemetry if ctx is not None else None
+        frac = None if prev is None else expected_flip_fraction(prev[1], sig)
+        if frac is None or frac > self.delta_threshold:
+            if tel is not None:
+                tel.note_delta(category)
             return "full", 1.0
         self._emit(ctx, category, "delta", flip=frac)
-        if note is not None:
-            note(category, flip_fraction=frac)
+        if tel is not None:
+            tel.note_delta(category, flip_fraction=frac)
         return "delta", delta_write_scale(frac, bits)
 
     def discard(self, device: Hashable, key: tuple, *, ctx=None,

@@ -103,7 +103,7 @@ def _device_span(ctx, d: int, frames: int):
     the instrumentation that makes the sharded wall regression *visible*:
     the per-device loop runs on the host sequentially, so its spans sum to
     the serial staging cost the modeled max-over-devices wall never pays."""
-    tr = getattr(ctx, "tracer", None)
+    tr = ctx.tracer
     if tr is None:
         return contextlib.nullcontext()
     return tr.span("scatter", lane=f"device{d}", device=d, frames=frames)
@@ -115,7 +115,7 @@ def _stage_span(ctx, d: int, frames: int):
     span, compute launch excluded).  Summed per flush this is the
     re-scatter tax a committed placement eliminates: on a resident hit the
     span closes in microseconds because nothing crosses."""
-    tr = getattr(ctx, "tracer", None)
+    tr = ctx.tracer
     if tr is None:
         return contextlib.nullcontext()
     return tr.span("scatter_stage", lane=f"device{d}", device=d,
@@ -124,7 +124,7 @@ def _stage_span(ctx, d: int, frames: int):
 
 def _gather_span(ctx, n_blocks: int):
     """Span over the host-side gather + reassembly of per-device blocks."""
-    tr = getattr(ctx, "tracer", None)
+    tr = ctx.tracer
     if tr is None:
         return contextlib.nullcontext()
     return tr.span("gather", lane="host", blocks=n_blocks)
@@ -242,9 +242,8 @@ class ShardedOpticalBackend(ExecutionBackend):
     def _survivor_pool(self, ctx) -> list[int]:
         """Logical device slots currently healthy: the fleet minus
         quarantined devices (device 0 serves alone when all are out)."""
-        q = getattr(ctx, "quarantine", None)
-        clock = getattr(ctx, "clock", None)
-        now = clock() if clock is not None else 0.0
+        q = ctx.quarantine
+        now = ctx.clock()
         n = max(1, int(ctx.n_devices))
         pool = [d for d in range(n)
                 if q is None or not q.is_quarantined(("device", d), now)]
@@ -267,7 +266,7 @@ class ShardedOpticalBackend(ExecutionBackend):
         changed frames re-ship.  Returns the placement, or ``None`` when
         placements do not apply (no cache, single device, frame-sharded
         mode, or the sequential off-mesh fallback)."""
-        res = getattr(ctx, "residency", None)
+        res = ctx.residency
         if res is None or not xs:
             return None
         if self._resolve_mode(category, xs, ctx) != "group":
@@ -314,21 +313,18 @@ class ShardedOpticalBackend(ExecutionBackend):
         pl = _Placement(pool=pool, devices=devices, assign=assign,
                         frames=len(xs))
         self._placements[pkey] = pl
-        tr = getattr(ctx, "tracer", None)
-        if tr is not None:
-            tr.instant("placement", lane="sched", event="commit",
-                       category=category, frames=len(xs),
-                       devices=len(pool),
-                       rebuilt=cur is not None)
-            tr.metrics.counter("placements", event="commit",
-                               category=category).inc()
+        ctx.telemetry.count("placements", event="commit", category=category)
+        if ctx.tracer is not None:
+            ctx.tracer.instant("placement", lane="sched", event="commit",
+                               category=category, frames=len(xs),
+                               devices=len(pool), rebuilt=cur is not None)
         return pl
 
     def _placement_for(self, category, xs, ctx) -> _Placement | None:
         """The committed placement covering every frame of ``xs``, if one
         exists and references only healthy devices; ``None`` routes the
         dispatch down the legacy re-scatter path."""
-        res = getattr(ctx, "residency", None)
+        res = ctx.residency
         if res is None or not xs:
             return None
         pl = self._placements.get(
@@ -337,10 +333,9 @@ class ShardedOpticalBackend(ExecutionBackend):
             return None
         if any(ctx.content_key(x) not in pl.assign for x in xs):
             return None
-        q = getattr(ctx, "quarantine", None)
+        q = ctx.quarantine
         if q is not None:
-            clock = getattr(ctx, "clock", None)
-            now = clock() if clock is not None else 0.0
+            now = ctx.clock()
             if any(q.is_quarantined(("device", d), now) for d in pl.pool):
                 return None
         return pl
@@ -349,14 +344,14 @@ class ShardedOpticalBackend(ExecutionBackend):
         """Quarantine/device-loss cleanup: every placement referencing the
         dead device drops, so the next commit rebuilds on survivors."""
         stale = [k for k, pl in self._placements.items() if d in pl.pool]
-        tr = getattr(ctx, "tracer", None)
         for k in stale:
             del self._placements[k]
-            if tr is not None:
-                tr.instant("placement", lane="sched", event="invalidate",
-                           category=k[0], device=d)
-                tr.metrics.counter("placements", event="invalidate",
-                                   category=k[0]).inc()
+            ctx.telemetry.count("placements", event="invalidate",
+                                category=k[0])
+            if ctx.tracer is not None:
+                ctx.tracer.instant("placement", lane="sched",
+                                   event="invalidate", category=k[0],
+                                   device=d)
 
     def _inner_run_on(self, category, shard, ctx, kernel, weights, device):
         """Run the inner backend with the context's ``stage_stream`` pinned
@@ -365,7 +360,7 @@ class ShardedOpticalBackend(ExecutionBackend):
         devices — two devices' same-shaped sub-groups stage into different
         physical write streams even under the sequential off-mesh
         fallback."""
-        prev = getattr(ctx, "stage_stream", "host")
+        prev = ctx.stage_stream
         ctx.stage_stream = ("device", device)
         try:
             return self.inner.run(category, shard, ctx, kernel=kernel,
@@ -431,14 +426,13 @@ class ShardedOpticalBackend(ExecutionBackend):
         if pl is not None:
             return self._run_group_placed(category, xs, ctx, kernel,
                                           weights, pl)
-        clock = getattr(ctx, "clock", None)
         # scatter only across survivors: quarantined devices sit out until
         # their probation window clears (with the whole fleet quarantined,
         # device 0 serves alone rather than the dispatch failing)
         pool = self._survivor_pool(ctx)
         # chaos-injected device loss is a property of THIS dispatch only;
         # the injector clears ctx.lost_devices after the run
-        lost = frozenset(getattr(ctx, "lost_devices", frozenset()) or ())
+        lost = ctx.lost_devices
         sizes = shard_sizes(len(xs), len(pool))
         devices = shard_devices(len(sizes))
         outs: list[jax.Array] = []
@@ -449,7 +443,7 @@ class ShardedOpticalBackend(ExecutionBackend):
             shard = xs[start:start + size]
             start += size
             d = pool[i]
-            t0 = clock() if clock is not None else 0.0
+            t0 = ctx.clock()
             try:
                 if d in lost:
                     raise DeviceLostError(d)
@@ -460,7 +454,8 @@ class ShardedOpticalBackend(ExecutionBackend):
                 # the shard's device failed mid-scatter: quarantine it and
                 # re-run the SAME shard on a surviving device — every frame
                 # still retires, from survivors, in order
-                self._note_device_fault(ctx, category, d, e)
+                ctx.telemetry.fault(category, e.kind, lane=f"device{d}",
+                                    device=d)
                 self._quarantine_device(ctx, d, reason=e.kind)
                 sv = next((s for s in pool if s != d and s not in lost), d)
                 with _device_span(ctx, sv, size):
@@ -468,7 +463,7 @@ class ShardedOpticalBackend(ExecutionBackend):
                                                 weights, devices, i, device=sv)
                 d = sv
             else:
-                dt = (clock() - t0) if clock is not None else 0.0
+                dt = ctx.clock() - t0
                 self._observe_shard(ctx, category, d, dt, c)
             outs.extend(o)
             costs.append(c)
@@ -489,7 +484,7 @@ class ShardedOpticalBackend(ExecutionBackend):
         drops its resident set, so a recovered device always re-stages.
         """
         if devices is not None:
-            res = getattr(ctx, "residency", None)
+            res = ctx.residency
             key = None
             if res is not None:
                 key = residency_key(ctx, shard, "shard")
@@ -525,8 +520,7 @@ class ShardedOpticalBackend(ExecutionBackend):
         per-device output blocks gather only at readout.  A device fault
         mid-dispatch quarantines the device, drops the placement, and
         re-runs the shard on a survivor — the next commit rebuilds."""
-        clock = getattr(ctx, "clock", None)
-        lost = frozenset(getattr(ctx, "lost_devices", frozenset()) or ())
+        lost = ctx.lost_devices
         slots: dict[int, list[int]] = {}
         for i, x in enumerate(xs):
             slots.setdefault(pl.assign[ctx.content_key(x)], []).append(i)
@@ -537,7 +531,7 @@ class ShardedOpticalBackend(ExecutionBackend):
             idxs = slots[slot]
             shard = [xs[i] for i in idxs]
             d = pl.pool[slot]
-            t0 = clock() if clock is not None else 0.0
+            t0 = ctx.clock()
             try:
                 if d in lost:
                     raise DeviceLostError(d)
@@ -545,7 +539,8 @@ class ShardedOpticalBackend(ExecutionBackend):
                     o, c = self._placed_dispatch(category, shard, ctx,
                                                  kernel, weights, pl, slot)
             except FaultError as e:
-                self._note_device_fault(ctx, category, d, e)
+                ctx.telemetry.fault(category, e.kind, lane=f"device{d}",
+                                    device=d)
                 # drops this placement too (see _quarantine_device), so
                 # the next commit rebuilds on the survivors
                 self._quarantine_device(ctx, d, reason=e.kind)
@@ -559,7 +554,7 @@ class ShardedOpticalBackend(ExecutionBackend):
                         sv % len(pl.devices), device=sv)
                 d = sv
             else:
-                dt = (clock() - t0) if clock is not None else 0.0
+                dt = ctx.clock() - t0
                 self._observe_shard(ctx, category, d, dt, c)
             for i, v in zip(idxs, o):
                 outs[i] = v
@@ -604,69 +599,33 @@ class ShardedOpticalBackend(ExecutionBackend):
         """Feed one healthy shard wall to the per-device straggler
         watchdog; ``patience`` consecutive stragglers quarantine the
         device (re-scattering subsequent groups across the survivors)."""
-        wd = getattr(ctx, "watchdog", None)
-        q = getattr(ctx, "quarantine", None)
-        if wd is None:
+        if ctx.watchdog is None:
             return
         base = cost.total_s if cost is not None else None
-        if not wd.observe(("device", self.name, d), dt_s, base):
-            if q is not None:
-                q.note_healthy(("device", d))
+        if not ctx.watchdog.observe(("device", self.name, d), dt_s, base):
+            if ctx.quarantine is not None:
+                ctx.quarantine.note_healthy(("device", d))
             return
-        tel = getattr(ctx, "telemetry", None)
-        if tel is not None:
-            tel.note_fault(category, "straggle")
-        tr = getattr(ctx, "tracer", None)
-        if tr is not None:
-            tr.instant("fault", lane=f"device{d}", category=category,
-                       device=d, kind="straggle", elapsed_s=dt_s)
-            tr.metrics.counter("faults", category=category,
-                               kind="straggle").inc()
-        if q is not None:
-            now = getattr(ctx, "clock", None)
-            ev = q.note_straggle(("device", d),
-                                 now() if now is not None else 0.0)
-            if ev is not None and tr is not None:
-                q0 = tr.now()
-                tr.record("quarantine", q0, q0 + (ev.until - ev.t),
-                          lane=f"device{d}", kind="async", key=str(ev.key),
-                          reason=ev.reason, level=ev.level)
-                tr.metrics.counter("quarantines", reason=ev.reason).inc()
-
-    def _note_device_fault(self, ctx, category, d, exc):
-        tel = getattr(ctx, "telemetry", None)
-        if tel is not None:
-            tel.note_fault(category, exc.kind)
-        tr = getattr(ctx, "tracer", None)
-        if tr is not None:
-            tr.instant("fault", lane=f"device{d}", category=category,
-                       device=d, kind=exc.kind)
-            tr.metrics.counter("faults", category=category,
-                               kind=exc.kind).inc()
+        ctx.telemetry.fault(category, "straggle", lane=f"device{d}",
+                            device=d, elapsed_s=dt_s)
+        if ctx.quarantine is not None:
+            ev = ctx.quarantine.note_straggle(("device", d), ctx.clock())
+            if ev is not None:
+                ctx.telemetry.quarantined(ev, lane=f"device{d}")
 
     def _quarantine_device(self, ctx, d, *, reason):
         # a quarantined device's memory is no longer trustworthy (and the
         # scheduler will route around it anyway): drop its resident set so
         # nothing serves stale bytes when it rejoins the pool, and every
         # placement that mapped frames onto it
-        res = getattr(ctx, "residency", None)
-        if res is not None:
-            res.invalidate_device(("device", d), ctx=ctx)
+        if ctx.residency is not None:
+            ctx.residency.invalidate_device(("device", d), ctx=ctx)
         self._drop_placements_for_device(ctx, d)
-        q = getattr(ctx, "quarantine", None)
-        if q is None:
+        if ctx.quarantine is None:
             return None
-        clock = getattr(ctx, "clock", None)
-        ev = q.quarantine(("device", d),
-                          clock() if clock is not None else 0.0,
-                          reason=reason)
-        tr = getattr(ctx, "tracer", None)
-        if tr is not None:
-            q0 = tr.now()
-            tr.record("quarantine", q0, q0 + (ev.until - ev.t),
-                      lane=f"device{d}", kind="async", key=str(ev.key),
-                      reason=ev.reason, level=ev.level)
-            tr.metrics.counter("quarantines", reason=ev.reason).inc()
+        ev = ctx.quarantine.quarantine(("device", d), ctx.clock(),
+                                       reason=reason)
+        ctx.telemetry.quarantined(ev, lane=f"device{d}")
         return ev
 
     # -- (b) frame sharding: tile frames onto multiple apertures ---------------
@@ -689,7 +648,7 @@ class ShardedOpticalBackend(ExecutionBackend):
         else:
             v = stack
         devices = shard_devices(len(sizes))
-        res = getattr(ctx, "residency", None) if devices is not None \
+        res = ctx.residency if devices is not None \
             else None
         blocks, costs, samples = [], [], []
         r0 = 0
@@ -755,7 +714,7 @@ class ShardedOpticalBackend(ExecutionBackend):
                             weights=weights)
         stack = jnp.stack(list(xs))
         devices = shard_devices(len(sizes))
-        res = getattr(ctx, "residency", None) if devices is not None \
+        res = ctx.residency if devices is not None \
             else None
         blocks, costs, samples = [], [], []
         r0 = 0

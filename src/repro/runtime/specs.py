@@ -1,7 +1,7 @@
-"""Shared demo/benchmark accelerator design points for the runtime.
+"""Shared demo accelerator design points for the runtime.
 
 ``BATCHED_4F`` is the batched 4f variant used by
-``examples/optical_offload.py`` and ``benchmarks/runtime_bench.py``: the
+``examples/optical_offload.py`` and the runtime tests: the
 prototype's architecture with upgraded peripherals — a 2048x2048
 ferroelectric SLM, PCIe/CoaXPress-class pixel links, column-parallel
 camera-class converters (higher resolution at lower rate, still

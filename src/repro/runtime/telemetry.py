@@ -31,10 +31,9 @@ from typing import Sequence
 
 from repro.core.accelerator import StepCost
 from repro.core.planner import CategoryProfile
-from repro.runtime.metrics import Histogram
+from repro.runtime.metrics import Histogram, MetricsRegistry
 
-__all__ = ["BackendStats", "DeltaStats", "DeviceStats", "RuntimeTelemetry",
-           "WindowStats"]
+__all__ = ["BackendStats", "RuntimeTelemetry"]
 
 # Backends whose measured wall time is honest *host* time for planning
 # (sharded-over-host still executes digitally, scattered or not).
@@ -73,105 +72,46 @@ class BackendStats:
             self.modeled = self.modeled + modeled
 
 
-@dataclasses.dataclass
-class DeviceStats:
-    """Boundary traffic one simulated device absorbed under sharded offload."""
-
-    invocations: int = 0      # sharded invocations this device took part in
-    samples_in: int = 0       # scalars through THIS device's DAC
-    samples_out: int = 0      # scalars back through THIS device's ADC
-
-
-@dataclasses.dataclass
-class DeltaStats:
-    """Delta-staging ledger for one category: how many written operands
-    took the partial (delta-encoded) write versus the full re-stage, and
-    the summed flip fraction of the delta writes — the mean flip fraction
-    is what the router feeds back into write-side deadline pricing."""
-
-    frames: int = 0           # operands staged as delta writes
-    full: int = 0             # written operands that re-staged in full
-    flip_sum: float = 0.0     # sum of delta writes' flip fractions
-
-    @property
-    def mean_flip_fraction(self) -> float:
-        return self.flip_sum / self.frames if self.frames else 0.0
-
-
-@dataclasses.dataclass
-class WindowStats:
-    """Per-engine pipeline-window occupancy for one (category, backend).
-
-    Recorded at every dispatch: how many of this engine's invocations were
-    in flight the moment the new one entered its window (including
-    itself), against the window depth it gated on.  The mean occupancy is
-    the overlap the engine *actually achieved* — the measured counterpart
-    of the cost model's ``engines=`` composition claim."""
-
-    dispatches: int = 0       # invocations gated through this window
-    in_flight_sum: int = 0    # sum of occupancy-at-dispatch (incl. self)
-    peak: int = 0             # deepest occupancy observed
-    depth: int = 0            # window depth at the last dispatch
-
-    def add(self, *, in_flight: int, depth: int) -> None:
-        self.dispatches += 1
-        self.in_flight_sum += in_flight
-        self.peak = max(self.peak, in_flight)
-        self.depth = depth
-
-    @property
-    def mean_occupancy(self) -> float:
-        return (self.in_flight_sum / self.dispatches
-                if self.dispatches else 0.0)
-
-
 # How many recent submit timestamps back the arrival-rate estimate (enough
 # to smooth Poisson burstiness, few enough to track a changing rate).
 _ARRIVAL_WINDOW = 64
 
 
 class RuntimeTelemetry:
-    """Records executor traffic and emits measured ``CategoryProfile``s."""
+    """Records executor traffic and emits measured ``CategoryProfile``s.
 
-    def __init__(self) -> None:
+    Every runtime event lands once, in :attr:`metrics` (a
+    :class:`MetricsRegistry` of labelled counters and histograms):
+
+      ``faults{category, kind}``         error / straggle / drift /
+                                         device_loss / fallback / reroute
+      ``recovery_s{category}``           first fault to a correct result
+      ``invocation_wall_s{category, backend}``  per-invocation wall
+      ``window_occupancy{category, backend}``   in-flight at dispatch
+      ``residency{category, event}``     hit / miss / eviction / ...
+      ``delta_flip{category}``, ``delta_full{category}``  write staging
+      ``device_invocations|device_samples_in|device_samples_out
+        {category, backend, device}``    sharded per-device traffic
+      ``retries``, ``release``, ``quarantines``, ``placements``
+
+    With a ``tracer`` attached, the event methods (:meth:`fault`,
+    :meth:`fallback`, :meth:`quarantined`) also emit the event's instant or
+    span on the tracer; without one they only count.  ``stats`` stays a
+    dataclass per ``(category, backend)`` because it sums a ``StepCost``.
+    """
+
+    def __init__(self, tracer=None) -> None:
         self.stats: dict[tuple[str, str], BackendStats] = \
             collections.defaultdict(BackendStats)
-        # (category, backend) -> device index -> per-device boundary traffic
-        self.device_stats: dict[tuple[str, str], dict[int, DeviceStats]] = \
-            collections.defaultdict(dict)
         # category -> recent submit timestamps (the arrival process itself,
         # recorded at submit rather than dispatch so held traffic still has
         # an honest rate estimate)
         self._submits: dict[str, collections.deque[float]] = \
             collections.defaultdict(
                 lambda: collections.deque(maxlen=_ARRIVAL_WINDOW))
-        # (category, backend) -> per-invocation wall-time histogram: the
-        # percentile view (p50/p95/p99) the multi-tenant SLO roadmap item
-        # needs — totals say how much, percentiles say how consistently
-        self._latency: dict[tuple[str, str], Histogram] = {}
-        # category -> fault-kind counter ("error" / "straggle" / "drift" /
-        # "device_loss" / "fallback" / "reroute"): the goodput-under-faults
-        # ledger the chaos bench and operators read
-        self.fault_counts: dict[str, collections.Counter] = \
-            collections.defaultdict(collections.Counter)
-        # category -> recovery-latency histogram: first fault of a dispatch
-        # to its successful (possibly degraded) completion
-        self._recovery: dict[str, Histogram] = {}
-        # category -> residency-event counter ("hit" / "miss" / "eviction"
-        # / "invalidation"): the operand-residency ledger — per-category
-        # hit rate is what the router weighs batch depth against
-        self.residency_counts: dict[str, collections.Counter] = \
-            collections.defaultdict(collections.Counter)
-        # category -> delta-staging ledger: delta-written vs fully
-        # re-staged operand counts and summed flip fractions — the
-        # write-side signal `replan` weighs alongside the hit rate
-        self.delta_stats: dict[str, DeltaStats] = \
-            collections.defaultdict(DeltaStats)
-        # (category, backend) -> pipeline-window occupancy: the per-engine
-        # in-flight depth each dispatch actually found — the measured
-        # overlap the `engines=` composed price is judged against
-        self.engine_windows: dict[tuple[str, str], WindowStats] = \
-            collections.defaultdict(WindowStats)
+        self.metrics = MetricsRegistry()
+        # the owning executor's Tracer (None = tracing off)
+        self.tracer = tracer
         self._t0: float | None = None
         self._window_s: float = 0.0
         self._in_window_s: float = 0.0  # recorded wall inside the window
@@ -217,7 +157,7 @@ class RuntimeTelemetry:
             return float("inf")
         return (len(ts) - 1) / span
 
-    # -- recording (called by the executor) ----------------------------------
+    # -- recording (called by the executor and the sharded backend) ----------
     def record(self, category: str, backend: str, *, calls: int,
                samples_in: int, samples_out: int, wall_s: float,
                modeled: StepCost | None = None,
@@ -227,70 +167,93 @@ class RuntimeTelemetry:
             calls=calls, samples_in=samples_in, samples_out=samples_out,
             wall_s=wall_s, modeled=modeled, bytes_in=bytes_in,
             bytes_out=bytes_out)
-        self._latency.setdefault((category, backend),
-                                 Histogram()).record(wall_s)
-        if per_device:
-            devs = self.device_stats[(category, backend)]
-            for i, (s_in, s_out) in enumerate(per_device):
-                st = devs.setdefault(i, DeviceStats())
-                st.invocations += 1
-                st.samples_in += int(s_in)
-                st.samples_out += int(s_out)
+        m = self.metrics
+        m.histogram("invocation_wall_s", category=category,
+                    backend=backend).record(wall_s)
+        for d, (s_in, s_out) in enumerate(per_device or ()):
+            labels = dict(category=category, backend=backend, device=d)
+            m.counter("device_invocations", **labels).inc()
+            m.counter("device_samples_in", **labels).inc(int(s_in))
+            m.counter("device_samples_out", **labels).inc(int(s_out))
         if self._t0 is not None:  # only in-window traffic offsets 'other'
             self._in_window_s += wall_s
 
-    def note_fault(self, category: str, kind: str) -> None:
-        """Count one fault event against ``category`` (the executor's
-        retry path, the sharded backend's per-device recovery, and the
-        drift-correction path all report through here)."""
-        self.fault_counts[category][kind] += 1
+    def count(self, name: str, n: int = 1, **labels) -> None:
+        """Add ``n`` to the counter ``name{labels}``."""
+        self.metrics.counter(name, **labels).inc(n)
 
-    def note_recovery(self, category: str, dt_s: float) -> None:
-        """Record one recovery latency: the span from a dispatch's first
-        fault to the caller having a correct result again."""
-        self._recovery.setdefault(category, Histogram()).record(max(dt_s,
-                                                                    0.0))
+    def fault(self, category: str, kind: str, *, lane: str = "sched",
+              parent=None, **attrs) -> None:
+        """One fault event (the executor's retry path, the watchdog, the
+        sharded backend's per-device recovery, the drift check): counted in
+        ``faults{category, kind}`` and, traced, a ``fault`` instant."""
+        self.count("faults", category=category, kind=kind)
+        if self.tracer is not None:
+            self.tracer.instant("fault", lane=lane, parent=parent,
+                                category=category, kind=kind, **attrs)
+
+    def fallback(self, category: str, *, backend: str, to: str, reason: str,
+                 kind: str | None = "fallback", parent=None) -> None:
+        """A dispatch served by ``to`` in place of ``backend``: counted in
+        ``faults{category, kind}`` (``"fallback"`` after retry exhaustion,
+        ``"reroute"`` around a quarantine; ``None`` when the fault that
+        forced it was already counted) and, traced, a ``fallback``
+        instant."""
+        if kind is not None:
+            self.count("faults", category=category, kind=kind)
+        if self.tracer is not None:
+            self.tracer.instant("fallback", lane="sched", parent=parent,
+                                category=category, backend=backend, to=to,
+                                reason=reason)
+
+    def quarantined(self, ev, *, lane: str = "sched", parent=None) -> None:
+        """A :class:`~repro.runtime.faults.QuarantineEvent`: counted in
+        ``quarantines{reason}`` and, traced, a ``quarantine`` span as long
+        as the window."""
+        self.count("quarantines", reason=ev.reason)
+        if self.tracer is not None:
+            q0 = self.tracer.now()
+            self.tracer.record("quarantine", q0, q0 + (ev.until - ev.t),
+                               lane=lane, kind="async", parent=parent,
+                               key=str(ev.key), reason=ev.reason,
+                               level=ev.level)
+
+    def recovered(self, category: str, dt_s: float) -> None:
+        """One recovery latency: the span from a dispatch's first fault to
+        the caller having a correct result again."""
+        self.metrics.histogram("recovery_s", category=category).record(
+            max(dt_s, 0.0))
 
     def note_window(self, category: str, backend: str, *,
-                    in_flight: int, depth: int) -> None:
-        """Record one dispatch's pipeline-window occupancy for the
-        ``(category, backend)`` engine (the executor reports at every
-        invocation, after gating on the engine's window)."""
-        self.engine_windows[(category, backend)].add(in_flight=in_flight,
-                                                     depth=depth)
+                    in_flight: int) -> None:
+        """One dispatch's pipeline-window occupancy for the ``(category,
+        backend)`` engine: how many of its invocations were in flight as
+        this one entered the window, itself included."""
+        self.metrics.histogram("window_occupancy", category=category,
+                               backend=backend).record(in_flight)
 
     def window_occupancy(self, category: str | None = None,
                          backend: str | None = None) -> float:
         """Mean in-flight-at-dispatch occupancy across the matching engine
         windows (dispatch-weighted); 0.0 when nothing dispatched."""
-        disp = occ = 0
-        for (cat, be), st in self.engine_windows.items():
-            if category is not None and cat != category:
-                continue
-            if backend is not None and be != backend:
-                continue
-            disp += st.dispatches
-            occ += st.in_flight_sum
-        return occ / disp if disp else 0.0
+        h = self.metrics.merged("window_occupancy", category=category,
+                                backend=backend)
+        return h.total / h.n if h is not None and h.n else 0.0
 
     def note_residency(self, category: str, event: str) -> None:
         """Count one residency-cache event ("hit" / "miss" / "eviction" /
-        "invalidation") against ``category`` (mirrored here by the
-        ``ResidencyCache`` whenever a context with telemetry is attached)."""
-        self.residency_counts[category][event] += 1
+        "invalidation" / "delta") against ``category``."""
+        self.count("residency", category=category, event=event)
 
     def residency_hit_rate(self, category: str | None = None,
                            ) -> float | None:
         """hits / (hits + misses) for ``category`` (overall when None);
         ``None`` before any residency lookup — no traffic is no claim,
         and the router treats it as rate 0."""
-        hits = misses = 0
-        for cat, c in self.residency_counts.items():
-            if category is not None and cat != category:
-                continue
-            hits += c.get("hit", 0)
-            misses += c.get("miss", 0)
-        total = hits + misses
+        hits = self.metrics.total("residency", category=category,
+                                  event="hit")
+        total = hits + self.metrics.total("residency", category=category,
+                                          event="miss")
         return None if total == 0 else hits / total
 
     def note_delta(self, category: str, *,
@@ -300,61 +263,50 @@ class RuntimeTelemetry:
         partial write at that measured LSB flip fraction; with ``None``
         it re-staged in full (first sighting, or a flip fraction past
         the delta threshold)."""
-        st = self.delta_stats[category]
         if flip_fraction is None:
-            st.full += 1
+            self.count("delta_full", category=category)
         else:
-            st.frames += 1
-            st.flip_sum += max(0.0, min(1.0, float(flip_fraction)))
+            self.metrics.histogram("delta_flip", category=category).record(
+                max(0.0, min(1.0, float(flip_fraction))))
 
     def delta_rate(self, category: str | None = None) -> float | None:
         """delta writes / all writes for ``category`` (overall when None);
         ``None`` before any write-side staging was classified — no traffic
         is no claim, and the router treats it as rate 0."""
-        frames = full = 0
-        for cat, st in self.delta_stats.items():
-            if category is not None and cat != category:
-                continue
-            frames += st.frames
-            full += st.full
-        total = frames + full
+        h = self.metrics.merged("delta_flip", category=category)
+        frames = h.n if h is not None else 0
+        total = frames + self.metrics.total("delta_full", category=category)
         return None if total == 0 else frames / total
 
     def mean_flip_fraction(self, category: str | None = None) -> float:
         """Mean LSB flip fraction across the observed delta writes for
         ``category`` (overall when None); 0.0 when none occurred."""
-        frames = 0
-        flips = 0.0
-        for cat, st in self.delta_stats.items():
-            if category is not None and cat != category:
-                continue
-            frames += st.frames
-            flips += st.flip_sum
-        return flips / frames if frames else 0.0
+        h = self.metrics.merged("delta_flip", category=category)
+        return h.total / h.n if h is not None and h.n else 0.0
+
+    @property
+    def fault_counts(self) -> dict[str, collections.Counter]:
+        """Read-only ``{category: Counter(kind -> events)}`` view of
+        ``faults``."""
+        out: dict[str, collections.Counter] = \
+            collections.defaultdict(collections.Counter)
+        for labels, c in self.metrics.select("faults"):
+            out[labels["category"]][labels["kind"]] += c.value
+        return out
 
     def faults_total(self, category: str | None = None) -> int:
         """Total fault events observed (for ``category``, or overall)."""
-        if category is not None:
-            return sum(self.fault_counts.get(category, {}).values())
-        return sum(sum(c.values()) for c in self.fault_counts.values())
+        return self.metrics.total("faults", category=category)
 
     def recovery_stats(self, category: str | None = None) -> dict | None:
         """``{n, mean_s, p50_s, p95_s}`` of recovery latency for
         ``category`` (merged across categories when None); ``None`` when
         nothing ever needed recovering."""
-        merged: Histogram | None = None
-        for cat, h in self._recovery.items():
-            if category is not None and cat != category:
-                continue
-            if merged is None:
-                merged = h.copy()
-            else:
-                merged.merge(h)
-        if merged is None or merged.n == 0:
+        h = self.metrics.merged("recovery_s", category=category)
+        if h is None or h.n == 0:
             return None
-        return {"n": merged.n, "mean_s": merged.total / merged.n,
-                "p50_s": merged.percentile(50),
-                "p95_s": merged.percentile(95)}
+        return {"n": h.n, "mean_s": h.total / h.n,
+                "p50_s": h.percentile(50), "p95_s": h.percentile(95)}
 
     def discount_window(self, wall_s: float) -> None:
         """Exclude ``wall_s`` of measurement overhead (e.g. the fidelity
@@ -423,32 +375,28 @@ class RuntimeTelemetry:
         ``{device_index: (samples_in, samples_out)}`` summed across
         backends.  Empty when the category never ran sharded."""
         out: dict[int, list[int]] = {}
-        for (cat, _backend), devs in self.device_stats.items():
-            if cat != category:
-                continue
-            for i, st in devs.items():
-                acc = out.setdefault(i, [0, 0])
-                acc[0] += st.samples_in
-                acc[1] += st.samples_out
+        for j, name in enumerate(("device_samples_in", "device_samples_out")):
+            for labels, c in self.metrics.select(name, category=category):
+                out.setdefault(int(labels["device"]), [0, 0])[j] += c.value
         return {i: (s[0], s[1]) for i, s in sorted(out.items())}
 
     def devices_observed(self, category: str | None = None) -> int:
         """Widest device fan-out any recorded invocation used (1 when no
         sharded traffic was recorded)."""
-        widest = 1
-        for (cat, _backend), devs in self.device_stats.items():
-            if category is not None and cat != category:
-                continue
-            widest = max(widest, len(devs))
-        return widest
+        seen: dict[tuple[str, str], int] = {}
+        for labels, _ in self.metrics.select("device_invocations",
+                                             category=category):
+            key = (labels["category"], labels["backend"])
+            seen[key] = seen.get(key, 0) + 1
+        return max([1, *seen.values()])
 
     def tile_sizes_observed(self, category: str) -> dict[int, int]:
         """Per-tile samples: ``{invocation depth: dispatch count}`` merged
         across backends — the tile granularity the executor *actually*
         dispatched at.  A monolithic K-deep flush shows ``{K: 1}``; the
         same group streamed through a ``tile_k=4`` budget shows
-        ``{4: K//4, ...}`` (plus a ragged tail entry).  Benchmarks assert
-        the budget-chosen ``tile_k`` against this — the tile the planner
+        ``{4: K//4, ...}`` (plus a ragged tail entry).  Tests assert the
+        budget-chosen ``tile_k`` against this — the tile the planner
         picked must be the tile the boundary saw."""
         out: dict[int, int] = {}
         for (cat, _backend), st in self.stats.items():
@@ -463,18 +411,8 @@ class RuntimeTelemetry:
         """Per-invocation wall-time histogram for ``(category, backend)``
         — or, with ``backend=None``, a merged copy across every backend
         that served the category.  ``None`` when no traffic recorded."""
-        if backend is not None:
-            h = self._latency.get((category, backend))
-            return None if h is None else h.copy()
-        merged: Histogram | None = None
-        for (cat, _b), h in self._latency.items():
-            if cat != category:
-                continue
-            if merged is None:
-                merged = h.copy()
-            else:
-                merged.merge(h)
-        return merged
+        return self.metrics.merged("invocation_wall_s", category=category,
+                                   backend=backend)
 
     def percentiles(self, category: str, backend: str | None = None,
                     ps: Sequence[float] = (50.0, 95.0, 99.0),
@@ -551,56 +489,19 @@ class RuntimeTelemetry:
             mine.modeled = mine.modeled + st.modeled
             for size, count in st.tiles.items():
                 mine.tiles[size] = mine.tiles.get(size, 0) + count
-        for key, devs in other.device_stats.items():
-            mine_devs = self.device_stats[key]
-            for i, st in devs.items():
-                acc = mine_devs.setdefault(i, DeviceStats())
-                acc.invocations += st.invocations
-                acc.samples_in += st.samples_in
-                acc.samples_out += st.samples_out
         for cat, ts in other._submits.items():
             mine_ts = self._submits[cat]
             merged = sorted(list(mine_ts) + list(ts))
             mine_ts.clear()
             mine_ts.extend(merged[-_ARRIVAL_WINDOW:])
-        for key, h in other._latency.items():
-            if key in self._latency:
-                self._latency[key].merge(h)
-            else:
-                self._latency[key] = h.copy()
-        for cat, counts in other.fault_counts.items():
-            self.fault_counts[cat].update(counts)
-        for cat, h in other._recovery.items():
-            if cat in self._recovery:
-                self._recovery[cat].merge(h)
-            else:
-                self._recovery[cat] = h.copy()
-        for cat, counts in other.residency_counts.items():
-            self.residency_counts[cat].update(counts)
-        for cat, st in other.delta_stats.items():
-            mine_d = self.delta_stats[cat]
-            mine_d.frames += st.frames
-            mine_d.full += st.full
-            mine_d.flip_sum += st.flip_sum
-        for key, st in other.engine_windows.items():
-            mine_w = self.engine_windows[key]
-            mine_w.dispatches += st.dispatches
-            mine_w.in_flight_sum += st.in_flight_sum
-            mine_w.peak = max(mine_w.peak, st.peak)
-            mine_w.depth = st.depth or mine_w.depth
+        self.metrics.merge(other.metrics)
         self._window_s += other._window_s
         self._in_window_s += other._in_window_s
 
     def reset(self) -> None:
         self.stats.clear()
-        self.device_stats.clear()
         self._submits.clear()
-        self._latency.clear()
-        self.fault_counts.clear()
-        self._recovery.clear()
-        self.residency_counts.clear()
-        self.delta_stats.clear()
-        self.engine_windows.clear()
+        self.metrics.reset()
         self._t0 = None
         self._window_s = 0.0
         self._in_window_s = 0.0
@@ -614,49 +515,12 @@ class RuntimeTelemetry:
                 f"out={st.samples_out} wall={st.wall_s:.4g}s "
                 f"modeled={st.modeled.total_s:.4g}s "
                 f"(conv {st.modeled.conversion_s:.4g}s)")
-            devs = self.device_stats.get((cat, backend))
-            if devs:
-                parts = [f"d{i}: in={d.samples_in} out={d.samples_out} "
-                         f"x{d.invocations}" for i, d in sorted(devs.items())]
-                rows.append(f"           devices[{len(devs)}] "
-                            + "; ".join(parts))
             if len(st.tiles) > 1:  # tiled / mixed-depth dispatch is news
                 parts = [f"depth{s} x{c}"
                          for s, c in sorted(st.tiles.items())]
                 rows.append("           tiles: " + "; ".join(parts))
-            h = self._latency.get((cat, backend))
-            if h is not None and h.n > 1:  # percentiles of one are noise
-                rows.append(
-                    f"           wall p50={h.percentile(50):.3g}s "
-                    f"p95={h.percentile(95):.3g}s "
-                    f"p99={h.percentile(99):.3g}s (n={h.n})")
-            w = self.engine_windows.get((cat, backend))
-            if w is not None and w.dispatches:
-                rows.append(
-                    f"           window depth={w.depth} "
-                    f"occupancy={w.mean_occupancy:.2f} peak={w.peak} "
-                    f"(n={w.dispatches})")
-        for cat, counts in sorted(self.fault_counts.items()):
-            parts = [f"{k} x{c}" for k, c in sorted(counts.items())]
-            row = f"  faults[{cat}]: " + "; ".join(parts)
-            rec = self.recovery_stats(cat)
-            if rec is not None:
-                row += (f" | recovery p50={rec['p50_s']:.3g}s "
-                        f"p95={rec['p95_s']:.3g}s (n={rec['n']})")
-            rows.append(row)
-        for cat, counts in sorted(self.residency_counts.items()):
-            parts = [f"{k} x{c}" for k, c in sorted(counts.items())]
-            row = f"  residency[{cat}]: " + "; ".join(parts)
-            rate = self.residency_hit_rate(cat)
-            if rate is not None:
-                row += f" | hit rate {rate:.0%}"
-            rows.append(row)
-        for cat, st in sorted(self.delta_stats.items()):
-            if st.frames or st.full:
-                rows.append(
-                    f"  delta[{cat}]: delta x{st.frames} full x{st.full}"
-                    f" | mean flip {st.mean_flip_fraction:.1%}")
         if self._window_s:
             rows.append(f"  window={self._window_s:.4g}s "
                         f"recorded={self.recorded_s():.4g}s")
+        rows.append(self.metrics.summary())
         return "\n".join(rows)

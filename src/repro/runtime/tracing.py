@@ -58,8 +58,8 @@ Design constraints (all load-bearing):
 * **Charged-time semantics.**  Leaf ``stage``/``compute`` spans mirror the
   executor's retirement accounting (charge from where the previous
   retirement ended, never bill pipeline overlap twice), so per-stage sums
-  reconcile with the measured flush wall — the invariant the bench gate
-  and the Perfetto export both rely on.
+  reconcile with the flush's invocation extent — the invariant the
+  tracing tests and the Perfetto export both rely on.
 
 Consumers: :mod:`repro.runtime.trace_export` (Chrome/Perfetto
 ``trace_event`` JSON and per-stage charged sums), the trace summary
@@ -133,9 +133,9 @@ class Tracer:
     :meth:`span` context manager keeps a per-thread active-span stack, so
     a backend that opens spans inside an instrumented dispatch nests under
     the invocation without the executor threading handles through every
-    call signature.  :attr:`metrics` is a :class:`MetricsRegistry` the
-    instrumented runtime feeds alongside spans (release-reason counters,
-    span-latency histograms).
+    call signature.  :attr:`metrics` is a :class:`MetricsRegistry` for the
+    serving engine's counters; the offload runtime counts its events in
+    ``RuntimeTelemetry.metrics``.
     """
 
     def __init__(self, clock: Callable[[], float] = time.perf_counter,

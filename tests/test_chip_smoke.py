@@ -40,7 +40,8 @@ def test_offload_and_serve_phases_pass_at_tiny_size(monkeypatch, capsys):
 
 def test_fft_reference_checks_catch_a_wrong_frame():
     frame = np.random.default_rng(0).random((32, 24), dtype=np.float32)
-    ref = cs.fft_reference(frame, PROTOTYPE_4F)
+    ref = cs.fft_reference(frame, (PROTOTYPE_4F.dac.bits,
+                                   PROTOTYPE_4F.adc.bits))
 
     class Handle:
         value = ref

@@ -21,6 +21,7 @@ import pytest
 from repro.core.accelerator import ANDERSON_MVM, PROTOTYPE_4F
 from repro.core.conversion import ConverterSpec
 from repro.runtime import (
+    DELTA_THRESHOLD,
     Fault,
     ManualClock,
     MemoryBudget,
@@ -103,10 +104,48 @@ def test_hit_miss_counters_and_hit_rate():
     assert ex.residency.hit_rate("conv") == 0.5
     # mirrored into telemetry: the router replans from this ledger
     assert ex.telemetry.residency_hit_rate("conv") == 0.5
-    assert ex.telemetry.residency_counts["conv"]["hit"] == 2
+    assert ex.telemetry.metrics.total("residency", category="conv",
+                                      event="hit") == 2
     # the summaries surface the ledger
     assert "residency" in ex.residency.summary()
-    assert "residency[conv]" in ex.telemetry.summary()
+    assert "residency category=conv event=hit: 2" in ex.telemetry.summary()
+
+
+def test_repeated_conv_stack_hits_and_drifting_frames_delta():
+    """A conv layer stack re-using its frames and kernel, primed then
+    flushed three more times, against the same stack with a quarter of
+    its frames drifting ~1% a flush and against fresh frames every flush:
+    repeats pay no DAC at a hit rate above one half, drifted flushes pay a
+    partial write strictly between a hit and a re-stage at a mean flip
+    fraction in (0, DELTA_THRESHOLD), and both retire bit-equal to a
+    residency-off executor."""
+    imgs, kernel, reps = _imgs(8), _kernel(), 3
+    plain = OffloadExecutor(SPEC, max_batch=8)
+
+    def flushes(ex, groups):
+        for grp in groups:
+            vals, costs = _flush(ex, "conv", grp, kernel=kernel)
+        return vals, costs[0]
+
+    hot = OffloadExecutor(SPEC, max_batch=8, residency=True)
+    hit_vals, hit = flushes(hot, [imgs] * (1 + reps))
+    cold = OffloadExecutor(SPEC, max_batch=8, residency=True)
+    _, restage = flushes(cold, [_imgs(8, seed=100 + r) for r in range(reps)])
+    drifted = [[im + 0.01 * jax.random.uniform(
+        jax.random.fold_in(jax.random.PRNGKey(500 + r), i), im.shape)
+        if i % 4 == 0 else im for i, im in enumerate(imgs)]
+        for r in range(reps)]
+    part = OffloadExecutor(SPEC, max_batch=8, residency=True)
+    delta_vals, delta = flushes(part, [imgs] + drifted)
+
+    assert hit.dac_s == 0.0
+    assert 0.0 < delta.dac_s < restage.dac_s
+    assert hot.telemetry.residency_hit_rate("conv") > 0.5
+    assert 0.0 < part.telemetry.mean_flip_fraction("conv") < DELTA_THRESHOLD
+    for got, want in ((hit_vals, flushes(plain, [imgs])[0]),
+                      (delta_vals, flushes(plain, [drifted[-1]])[0])):
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
 
 
 def test_hit_priced_read_side_only():

@@ -15,6 +15,7 @@ from repro.runtime import (
     FidelityChecker,
     OffloadExecutor,
     PlanRouter,
+    Quarantine,
     RuntimeTelemetry,
     available_backends,
     get_backend,
@@ -872,8 +873,7 @@ def test_telemetry_merge_reset_cover_every_field():
     import collections
 
     expected = sorted([
-        "stats", "device_stats", "_submits", "_latency", "fault_counts",
-        "_recovery", "residency_counts", "delta_stats", "engine_windows",
+        "stats", "_submits", "metrics", "tracer",
         "_t0", "_window_s", "_in_window_s",
     ])
     tel = RuntimeTelemetry()
@@ -892,17 +892,22 @@ def test_telemetry_merge_reset_cover_every_field():
                bytes_in=32768, bytes_out=32768)
     tel.record("conv", "host", calls=1, samples_in=4096, samples_out=4096,
                wall_s=0.1)
-    tel.note_fault("fft", "error")
-    tel.note_fault("fft", "straggle")
-    tel.note_recovery("fft", 0.25)
+    tel.fault("fft", "error")
+    tel.fault("fft", "straggle")
+    tel.fallback("fft", backend="optical-sim", to="host",
+                 reason="retry-exhausted")
+    tel.quarantined(Quarantine().quarantine(("category", "fft"), 0.0,
+                                            reason="retry-exhausted"))
+    tel.recovered("fft", 0.25)
+    tel.count("retries", category="fft", backend="optical-sim")
     tel.note_residency("fft", "hit")
     tel.note_residency("fft", "miss")
     tel.note_residency("conv", "eviction")
     tel.note_delta("fft", flip_fraction=0.125)
     tel.note_delta("fft")
     tel.note_delta("conv", flip_fraction=0.25)
-    tel.note_window("fft", "optical-sim", in_flight=2, depth=2)
-    tel.note_window("conv", "host", in_flight=1, depth=3)
+    tel.note_window("fft", "optical-sim", in_flight=2)
+    tel.note_window("conv", "host", in_flight=1)
     tel.stop()
 
     def norm(v):
@@ -928,10 +933,17 @@ def test_telemetry_merge_reset_cover_every_field():
     merged.merge(tel)
     assert merged.stats[("fft", "optical-sim")].calls == 4
     assert merged.fault_counts["fft"]["error"] == 2
-    assert merged.residency_counts["fft"]["hit"] == 2
-    assert merged.delta_stats["fft"].frames == 2
-    assert merged.delta_stats["fft"].full == 2
-    assert merged.delta_stats["fft"].flip_sum == pytest.approx(0.25)
+    assert merged.fault_counts["fft"]["fallback"] == 2
+    assert merged.metrics.total("quarantines") == 2
+    assert merged.metrics.total("residency", category="fft",
+                                event="hit") == 2
+    assert merged.delta_rate("fft") == pytest.approx(0.5)
+    assert merged.metrics.total("delta_full", category="fft") == 2
+    assert merged.mean_flip_fraction("fft") == pytest.approx(0.125)
+    assert merged.recovery_stats("fft")["n"] == 2
+    assert merged.device_samples("fft") == {0: (8192, 8192),
+                                            1: (8192, 8192)}
+    assert merged.window_occupancy("fft") == 2.0
 
     tel.reset()
     assert snapshot(tel) == snapshot(RuntimeTelemetry())
@@ -1001,7 +1013,7 @@ def test_pipeline_window_accessors_and_validation():
 
 def test_window_occupancy_telemetry_recorded_per_engine():
     """Every dispatch notes its engine's in-flight occupancy; two engines
-    in one flush land separate WindowStats rows."""
+    in one flush land separate ``window_occupancy`` histograms."""
     imgs = _imgs(4, (16, 16))
     k = jnp.zeros((16, 16)).at[0, 0].set(1.0)
     ex = OffloadExecutor(LANED_4F, max_batch=2, pipeline_depth=2)
@@ -1010,7 +1022,11 @@ def test_window_occupancy_telemetry_recorded_per_engine():
         ex.submit("conv", im, kernel=k, backend="host")
     ex.flush()
     tel = ex.telemetry
-    assert tel.engine_windows[("fft", "host")].dispatches == 2
-    assert tel.engine_windows[("conv", "host")].dispatches == 2
+    fft_w = tel.metrics.histogram("window_occupancy", category="fft",
+                                  backend="host")
+    conv_w = tel.metrics.histogram("window_occupancy", category="conv",
+                                   backend="host")
+    assert fft_w.n == 2 and conv_w.n == 2
     assert 1.0 <= tel.window_occupancy("fft") <= 2.0
-    assert tel.engine_windows[("fft", "host")].depth == 2
+    # no dispatch ever found its engine's window deeper than its depth
+    assert fft_w.max <= ex.pipeline_window_for("fft") == 2

@@ -304,6 +304,38 @@ def test_burst_arrivals_estimate_infinite_rate_and_hold():
     assert ex.telemetry.stats[("fft", "optical-sim")].invocations == 1
 
 
+def test_holding_beats_draining_under_poisson_trickle():
+    """One seeded Poisson trickle (200 Hz, 48 arrivals) on a ManualClock:
+    drain-on-flush crosses once an arrival, while the scheduler holds
+    groups open under a 50 ms deadline, so it coalesces more calls and
+    more boundary samples into each crossing."""
+    rng = np.random.RandomState(0)
+    gaps = rng.exponential(1.0 / 200.0, size=48)
+    imgs = _imgs(48, (16, 16))
+
+    def crossings(held):
+        clk = ManualClock()
+        ex = OffloadExecutor(SPEC, max_batch=8, clock=clk)
+        sched = OffloadScheduler(ex, deadline_s=0.05, clock=clk) \
+            if held else None
+        for gap, im in zip(gaps, imgs):
+            clk.advance(float(gap))
+            if held:
+                sched.submit("fft", im)
+            else:
+                ex.submit("fft", im)
+                ex.flush()
+        ex.drain()
+        st = ex.telemetry.stats[("fft", "optical-sim")]
+        assert st.calls == len(imgs)
+        return st.calls / st.invocations, st.samples_in / st.invocations
+
+    drain_occ, drain_samples = crossings(held=False)
+    held_occ, held_samples = crossings(held=True)
+    assert held_occ > drain_occ, (held_occ, drain_occ)
+    assert held_samples > drain_samples, (held_samples, drain_samples)
+
+
 def test_hold_time_priced_into_invocation_cost():
     """The modeled wall honestly charges the queueing delay holding spent
     (StepCost.hold_s) — and eager executors price zero hold."""

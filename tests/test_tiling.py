@@ -323,6 +323,23 @@ def test_executor_tiles_groups_against_the_budget():
     assert ex.telemetry.bytes_per_frame("fft") == 2 * 16 * 12 * 4
 
 
+def test_planned_tile_is_the_dispatched_tile():
+    """The tiles ``choose_tile`` plans for a released group under the
+    executor's budget are the invocation depths it dispatches, as
+    telemetry records them."""
+    n = 16 * 12
+    budget = _budget_for_frames(n, 3)
+    plan = choose_tile(n, 16, budget, pipeline_depth=2)
+    assert 1 < plan.tile_k < 16
+    ex = OffloadExecutor(SPEC, max_batch=16, mem_budget=budget)
+    for im in _imgs(16, (16, 12)):
+        ex.submit("fft", im)
+    ex.flush()
+    tiles = ex.telemetry.tile_sizes_observed("fft")
+    assert max(tiles) == plan.tile_k
+    assert tiles == {s: plan.sizes().count(s) for s in set(plan.sizes())}
+
+
 def test_executor_tile_k_override_beats_budget():
     ex = OffloadExecutor(SPEC, max_batch=8, tile_k=3,
                          mem_budget=MemoryBudget.unlimited())
