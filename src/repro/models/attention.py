@@ -14,6 +14,11 @@ Decode caches:
   MLA:  the compressed (B, S_max, kv_rank + rope_dim) latent cache; decode
         uses the *absorbed* form (score via W_uk-absorbed queries against
         the latent cache) so neither K nor V is ever materialized.
+The decode functions also take the cache of a whole scanned layer stack
+(every leaf with a leading layer axis) and a ``layer`` index: they then
+write the step's one position (a K and a V row and its ``slot_pos``, or
+one latent row) into that layer of the stacked buffer, which the scan
+carries and updates in place, and attend over the layer's view of it.
 """
 
 from __future__ import annotations
@@ -127,25 +132,40 @@ def init_gqa_cache(cfg: ModelConfig, batch: int, max_len: int, window: int = 0):
     }
 
 
+def _write(buf: jax.Array, index: tuple, rows: jax.Array,
+           layer: jax.Array | None) -> jax.Array:
+    """``buf[index] = rows``, at ``buf[layer][index]`` in a stacked cache."""
+    if layer is not None:
+        index = (layer,) + index
+    return buf.at[index].set(rows.astype(buf.dtype))
+
+
+def _view(buf: jax.Array, layer: jax.Array | None) -> jax.Array:
+    """One layer's buffer: ``buf[layer]`` of a stacked cache."""
+    return buf if layer is None else buf[layer]
+
+
 def gqa_decode(cfg: ModelConfig, p: dict, x: jax.Array, cache: dict,
                pos: jax.Array, *, window: int = 0,
-               cross_kv: jax.Array | None = None):
-    """One-token decode. x: (B, 1, D); pos: (B,) int32 per-lane positions."""
+               cross_kv: jax.Array | None = None,
+               layer: jax.Array | None = None):
+    """One-token decode. x: (B, 1, D); pos: (B,) int32 per-lane positions.
+    With ``layer``, ``cache`` is a stacked cache (see the module doc)."""
     b = x.shape[0]
     h, hk, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
     q, k, v = _qkv(cfg, p, x)
     q = rope(q, pos[:, None], theta=cfg.rope_theta, pct=cfg.rope_pct)
     k = rope(k, pos[:, None], theta=cfg.rope_theta, pct=cfg.rope_pct)
-    size = cache["k"].shape[2]
+    size = cache["k"].shape[-2]
     slot = pos % size if window > 0 else jnp.minimum(pos, size - 1)
     bi = jnp.arange(b)[:, None]
     hi = jnp.arange(hk)[None, :]
-    ck = cache["k"].at[bi, hi, slot[:, None], :].set(
-        k[:, 0].astype(cache["k"].dtype))
-    cv = cache["v"].at[bi, hi, slot[:, None], :].set(
-        v[:, 0].astype(cache["v"].dtype))
-    spos = cache["slot_pos"].at[jnp.arange(b), slot].set(pos)
-    new_cache = {"k": ck, "v": cv, "slot_pos": spos}
+    row = (bi, hi, slot[:, None], slice(None))
+    new_cache = {"k": _write(cache["k"], row, k[:, 0], layer),
+                 "v": _write(cache["v"], row, v[:, 0], layer),
+                 "slot_pos": _write(cache["slot_pos"], (jnp.arange(b), slot),
+                                    pos, layer)}
+    ck, cv, spos = (_view(new_cache[n], layer) for n in ("k", "v", "slot_pos"))
 
     if cross_kv is not None:
         raise NotImplementedError("use gqa_decode_cross for cross attention")
@@ -225,17 +245,20 @@ def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int):
 
 
 def mla_decode(cfg: ModelConfig, p: dict, x: jax.Array, cache: dict,
-               pos: jax.Array):
+               pos: jax.Array, *, layer: jax.Array | None = None):
     """Absorbed-matrix MLA decode: attention runs entirely in latent space.
-    pos: (B,) int32 per-lane positions."""
+    pos: (B,) int32 per-lane positions.  With ``layer``, ``cache`` is a
+    stacked cache (see the module doc)."""
     m = cfg.mla
     h = cfg.n_heads
     b = x.shape[0]
     q_nope, q_rope = _mla_q(cfg, p, x, pos[:, None])       # (B,1,H,*)
     c_kv, k_rope = _mla_latent(cfg, p, x, pos[:, None])
     new_lat = jnp.concatenate([c_kv, k_rope], axis=-1)     # (B,1,D_lat)
-    lat = cache["latent"].at[jnp.arange(b), pos, :].set(
-        new_lat[:, 0].astype(cache["latent"].dtype))
+    new_cache = {"latent": _write(cache["latent"],
+                                  (jnp.arange(b), pos, slice(None)),
+                                  new_lat[:, 0], layer)}
+    lat = _view(new_cache["latent"], layer)
     c_all, r_all = lat[..., :m.kv_lora_rank], lat[..., m.kv_lora_rank:]
 
     # absorb W_uk into the query: q_eff[b,h,r] = sum_d q_nope[b,h,d] W_uk[r, h*d]
@@ -252,4 +275,4 @@ def mla_decode(cfg: ModelConfig, p: dict, x: jax.Array, cache: dict,
     wuv = p["w_uv"].astype(x.dtype).reshape(m.kv_lora_rank, h, m.v_head_dim)
     out = jnp.einsum("bhr,rhd->bhd", out_lat.astype(x.dtype), wuv)
     y = out.reshape(b, 1, h * m.v_head_dim) @ p["w_o"].astype(x.dtype)
-    return y, {"latent": lat}
+    return y, new_cache

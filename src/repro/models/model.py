@@ -108,17 +108,33 @@ def _block_full(cfg: ModelConfig, kind: str, p: dict, x: jax.Array, *,
     raise ValueError(f"unknown block kind {kind!r}")
 
 
+def _state_step(step, state: dict, layer: jax.Array | None):
+    """``step(state) -> (y, new state)`` of a recurrent block.  In a stacked
+    cache (``layer`` given) it runs on that layer's state and writes the new
+    state back at ``layer``."""
+    if layer is None:
+        return step(state)
+    y, new = step(jax.tree_util.tree_map(lambda a: a[layer], state))
+    return y, jax.tree_util.tree_map(
+        lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, layer, 0),
+        state, new)
+
+
 def _block_decode(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
                   cache: dict, pos: jax.Array, *, dense: bool,
-                  enc_out: jax.Array | None):
-    """One-token step.  Returns (x, new_cache, expert_load_or_None)."""
+                  enc_out: jax.Array | None, layer: jax.Array | None = None):
+    """One-token step.  Returns (x, new_cache, expert_load_or_None).  With
+    ``layer``, ``cache`` is the scanned stack's cache, every leaf with a
+    leading layer axis, and the new cache is that buffer with only this
+    layer's new state written into it."""
     if kind == "attn":
         h_in = rms_norm(x, p["ln1"], cfg.norm_eps)
         if cfg.mla is not None:
-            y, cache = attn.mla_decode(cfg, p["attn"], h_in, cache, pos)
+            y, cache = attn.mla_decode(cfg, p["attn"], h_in, cache, pos,
+                                       layer=layer)
         else:
             y, cache = attn.gqa_decode(cfg, p["attn"], h_in, cache, pos,
-                                       window=cfg.local_window)
+                                       window=cfg.local_window, layer=layer)
         x = x + y
         if enc_out is not None and "xattn" in p:
             xh = rms_norm(x, p["ln_x"], cfg.norm_eps)
@@ -132,18 +148,21 @@ def _block_decode(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
 
     if kind == "rglru":
         h_in = rms_norm(x, p["ln1"], cfg.norm_eps)
-        y, cache = rec.rglru_decode(cfg, p["rglru"], h_in, cache)
+        y, cache = _state_step(
+            lambda st: rec.rglru_decode(cfg, p["rglru"], h_in, st), cache, layer)
         x = x + y
         return x + mlp_apply(cfg, p["mlp"], rms_norm(x, p["ln2"], cfg.norm_eps)), cache, None
 
     if kind == "mlstm":
         h_in = rms_norm(x, p["ln1"], cfg.norm_eps)
-        y, cache = rec.mlstm_decode(cfg, p["mlstm"], h_in, cache)
+        y, cache = _state_step(
+            lambda st: rec.mlstm_decode(cfg, p["mlstm"], h_in, st), cache, layer)
         return x + y, cache, None
 
     if kind == "slstm":
         h_in = rms_norm(x, p["ln1"], cfg.norm_eps)
-        y, cache = rec.slstm_decode(cfg, p["slstm"], h_in, cache)
+        y, cache = _state_step(
+            lambda st: rec.slstm_decode(cfg, p["slstm"], h_in, st), cache, layer)
         x = x + y
         return x + rec.slstm_ffn(p["slstm"], rms_norm(x, p["ln2"], cfg.norm_eps)), cache, None
 
@@ -442,20 +461,22 @@ class LM:
                     loads.append(l)
 
         if "stack" in params:
-            def body(xx, inp):
-                lp, lc = inp
-                ncs, ls = {}, []
+            # the stacked cache rides in the carry: each layer writes its
+            # one new position into it in place and reads its own view
+            def body(carry, lp):
+                xx, lc, i = carry
+                lc, ls = dict(lc), []
                 for key in sorted(lp.keys(), key=lambda s: int(s.split("_")[0])):
                     kind = key.split("_", 1)[1]
-                    xx, nc, l = _block_decode(cfg, kind, lp[key], xx, lc[key],
-                                              pos, dense=False, enc_out=enc_out)
-                    ncs[key] = nc
+                    xx, lc[key], l = _block_decode(
+                        cfg, kind, lp[key], xx, lc[key], pos, dense=False,
+                        enc_out=enc_out, layer=i)
                     ls.append(l)
-                return xx, (ncs, _stack_loads(ls))
+                return (xx, lc, i + 1), _stack_loads(ls)
 
-            x, (stack_cache, stack_loads) = jax.lax.scan(
-                body, x, (params["stack"], cache["stack"]))
-            new_cache["stack"] = stack_cache
+            (x, new_cache["stack"], _), stack_loads = jax.lax.scan(
+                body, (x, cache["stack"], jnp.zeros((), jnp.int32)),
+                params["stack"])
             loads.append(stack_loads)
 
         if "tail" in params:

@@ -11,6 +11,12 @@ Static shapes: one compilation for prefill (per prompt length bucket) and
 one for decode.  The decode step function is exactly what the decode_32k /
 long_500k dry-run cells lower.
 
+The decode program does not donate ``engine.cache``: the cache a step
+started from stays readable after it, so a caller may keep it across
+``step()``.  That costs one copy of the stacked cache a step, which the
+program then updates in place, one position a layer (``LM.decode_step``);
+donating the cache would remove that copy and delete the old cache.
+
 Analog offload (opt-in): pass ``offload=`` a ``repro.runtime``
 ``OffloadScheduler``, ``PlanRouter``, or bare ``OffloadExecutor`` and
 attention-adjacent FFT/conv work — e.g. spectral retrieval scoring or conv

@@ -28,3 +28,28 @@ except ImportError:  # tier-1 runs fixed-example fallbacks instead
 @pytest.fixture(scope="session")
 def rng():
     return jax.random.PRNGKey(0)
+
+
+def _copy_bytes(hlo: str, shapes) -> int:
+    """Bytes that the ``copy`` ops of the compiled program ``hlo`` write
+    into buffers of any of ``shapes`` (unit dims aside)."""
+    import math
+    import re
+
+    def squeeze(shape):
+        return tuple(d for d in shape if d != 1)
+
+    wanted = {squeeze(s) for s in shapes}
+    item = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4}
+    total = 0
+    for dt, dims in re.findall(r"=\s*(\w+)\[([\d,]*)\]\{[^ ]*\s+copy\(", hlo):
+        shape = tuple(int(d) for d in dims.split(",") if d)
+        if squeeze(shape) in wanted:
+            total += math.prod(shape) * item[dt]
+    return total
+
+
+@pytest.fixture(scope="session")
+def copy_bytes():
+    """``(hlo text, shapes) -> bytes`` copied into buffers of those shapes."""
+    return _copy_bytes

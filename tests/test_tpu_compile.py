@@ -145,3 +145,33 @@ def test_stablelm_decode_step_fits_one_chip(one_chip):
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.argument_size_in_bytes > 6 * 10**9   # the f32 weights
     assert total < HBM_BYTES, total
+
+
+def test_stablelm_decode_step_writes_the_cache_in_place(one_chip,
+                                                         copy_bytes):
+    """The layer loop carries the stacked K/V and writes one row a layer:
+    the chip's compiler leaves no copy of a layer's K or V in the step,
+    and with the cache donated no copy of the K/V cache at all."""
+    from repro.configs import get_config
+    from repro.models import LM, param_shape_structs
+
+    cfg = get_config("stablelm-1.6b")
+    model = LM(cfg)
+
+    def place(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(place, param_shape_structs(cfg))
+    cache = jax.tree_util.tree_map(
+        place, jax.eval_shape(lambda: model.init_cache(4, 512)))
+    tokens = _sds((4, 1), one_chip, jnp.int32)
+    kv = [cache["stack"]["0_attn"][n] for n in ("k", "v")]
+    layer = [a.shape[1:] for a in kv]
+    whole = [a.shape for a in kv]
+
+    def hlo(**kw):
+        return jax.jit(model.decode_step, **kw).lower(
+            params, cache, tokens).compile().as_text()
+
+    assert copy_bytes(hlo(), layer) == 0
+    assert copy_bytes(hlo(donate_argnums=(1,)), layer + whole) == 0
